@@ -1006,11 +1006,9 @@ def table7_memops(csv: CSV, fast: bool):
     dst = jnp.arange(0, 32, dtype=jnp.int32)
 
     def contract():
-        out = pool.k
         from repro.kernels import ops
-        out = ops.migrate_blocks(out, src, dst, use_kernel=False)
-        out.block_until_ready()
-        return out
+        pool.k = ops.migrate_blocks(pool.k, src, dst).block_until_ready()
+        return pool.k
     _, dt = timed(contract, repeat=3)
     csv.add("table7.contraction.vectorized", dt * 1e6,
             f"latency={dt*1e3:.2f}ms;blocks=32")
@@ -1066,12 +1064,17 @@ def kernel_microbench(csv: CSV, fast: bool):
     from repro.kernels import ops
     key = jax.random.PRNGKey(0)
 
-    # block migration (ref path = production path on CPU)
+    # block migration (the pool is donated: each call migrates the last
+    # result back and forth)
     x = jax.random.normal(key, (8, 512, 16, 8, 64), jnp.float32)
-    src = jnp.arange(480, 512, dtype=jnp.int32)
-    dst = jnp.arange(0, 32, dtype=jnp.int32)
-    _, dt = timed(lambda: ops.migrate_blocks(x, src, dst).block_until_ready(),
-                  repeat=3)
+    src = np.arange(480, 512, dtype=np.int32)
+    dst = np.arange(0, 32, dtype=np.int32)
+
+    def migrate():
+        nonlocal x
+        x = ops.migrate_blocks(x, src, dst).block_until_ready()
+        return x
+    _, dt = timed(migrate, repeat=3)
     csv.add("kernel.block_migration.32x1MB", dt * 1e6,
             f"GBps={(32*8*16*8*64*4*2/dt)/1e9:.1f}")
 
@@ -1081,14 +1084,14 @@ def kernel_microbench(csv: CSV, fast: bool):
     vp = jax.random.normal(key, (256, bs, KH, D))
     tables = jax.random.randint(key, (B, maxb), 0, 256)
     lengths = jnp.full((B,), maxb * bs)
-    _, dt = timed(lambda: ops.paged_attention_op(
+    _, dt = timed(lambda: ops.paged_attention(
         q, kp, vp, tables, lengths).block_until_ready(), repeat=5)
     csv.add("kernel.paged_attention.b8h16", dt * 1e6,
             f"ctx={maxb*bs}")
 
     # multi-query extension (speculative verify / chunked-prefill appends)
     qm = jax.random.normal(key, (B, 4, H, D))
-    _, dt = timed(lambda: ops.paged_attention_op(
+    _, dt = timed(lambda: ops.paged_attention(
         qm, kp, vp, tables, lengths).block_until_ready(), repeat=5)
     csv.add("kernel.paged_attention.b8t4h16", dt * 1e6,
             f"ctx={maxb*bs};T=4")
@@ -1096,7 +1099,7 @@ def kernel_microbench(csv: CSV, fast: bool):
     S = 512 if fast else 1024
     q = jax.random.normal(key, (2, S, 8, 64), jnp.float32)
     k = jax.random.normal(key, (2, S, 8, 64), jnp.float32)
-    _, dt = timed(lambda: ops.flash_attention_op(
+    _, dt = timed(lambda: ops.flash_attention(
         q, k, k, causal=True).block_until_ready(), repeat=3)
     csv.add(f"kernel.flash_attention.s{S}", dt * 1e6,
             f"gflops={(4*2*8*S*S*64/2/dt)/1e9:.1f}")

@@ -25,7 +25,7 @@ def main():
     mm = ElasticMemoryManager(
         bm, draft_blocks=draft_blocks, tau_low_frac=0.15, t_persist=2,
         offload_latency=0.004, reload_latency=0.004,
-        migrate_fn=lambda plan: (pool.migrate(plan, use_kernel=True), 0.002)[1])
+        migrate_fn=lambda plan: (pool.migrate(plan), 0.002)[1])
 
     print(f"pool: {nb} blocks x {bs} tokens; draft model worth "
           f"{draft_blocks} blocks; tau_low={mm.tau_low} blocks")

@@ -49,17 +49,23 @@ _MODULES = {
 
 ASSIGNED_ARCHS: Tuple[str, ...] = tuple(k for k in _MODULES if k != "paper-7b")
 
+# arch name -> (target, draft); cuts of a pair for a chip are registered
+# beside the published configurations under their own names
+_PAIRS: Dict[str, Tuple[ModelConfig, ModelConfig]] = {
+    name: (mod.CONFIG, mod.DRAFT) for name, mod in _MODULES.items()}
+_PAIRS["paper-7b-1chip"] = (paper_7b.ONE_CHIP, paper_7b.DRAFT)
+
 
 def list_archs():
-    return list(_MODULES.keys())
+    return list(_PAIRS)
 
 
 def get_config(name: str) -> ModelConfig:
-    return _MODULES[name].CONFIG
+    return _PAIRS[name][0]
 
 
 def get_draft_config(name: str) -> ModelConfig:
-    return _MODULES[name].DRAFT
+    return _PAIRS[name][1]
 
 
 def get_shape(name: str) -> ShapeConfig:

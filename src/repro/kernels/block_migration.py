@@ -2,80 +2,52 @@
 adapted to TPU with Pallas.
 
 The paper launches one thread block per migrated KV block and moves it with
-vectorised load/stores.  On TPU the analogue is a Pallas grid over
-(migration entries x row chunks): the scalar-prefetched migration map drives
-the BlockSpec index_map, so the DMA engine pipelines the non-contiguous
-HBM->VMEM->HBM block copies.  ``input_output_aliases`` makes the move
-in-place (no second pool allocation), matching the Triton kernel's in-place
-compaction semantics.
+vectorised load/stores.  On TPU the block never needs to visit VMEM: one
+grid step per migration entry issues a single DMA that copies block
+``src[i]`` of every layer to block ``dst[i]`` straight from HBM to HBM.
+The scalar-prefetched migration map supplies the offsets, the pool keeps
+its own layout (no transpose; the paged pools' ``(L, num_blocks,
+block_size, KH * hd)`` blocks are lane-dense for every head size), and
+``input_output_aliases`` makes the move in place — no second pool
+allocation, matching the Triton kernel's in-place compaction semantics.
+
+Sources and destinations must be disjoint (the CoW fork and contraction
+plans guarantee it); a repeated entry copies the same bytes again, which
+is how ``ops.migrate_blocks`` pads the map to a bucketed length.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ref import migrate_blocks_ref
 
-# rows are copied in chunks of this many elements (8x128-aligned)
-_CHUNK = 1024
-
-
-def _migrate_kernel(src_ref, dst_ref, x_ref, o_ref):
-    # one (migration entry, chunk) cell: pure copy through VMEM
-    o_ref[...] = x_ref[...]
+def _migrate_kernel(src_ref, dst_ref, x_hbm, o_hbm, sem):
+    i = pl.program_id(0)
+    copy = pltpu.make_async_copy(x_hbm.at[:, pl.ds(src_ref[i], 1)],
+                                 o_hbm.at[:, pl.ds(dst_ref[i], 1)], sem)
+    copy.start()
+    copy.wait()
 
 
-def _migrate_rows_pallas(x, src, dst, *, interpret=True):
-    """x: (num_blocks, row) float; src/dst: (M,) int32."""
-    nb, row = x.shape
-    chunk = min(_CHUNK, row)
-    assert row % chunk == 0, (row, chunk)
-    grid = (src.shape[0], row // chunk)
-
+def migrate_blocks(pool, src, dst, *, interpret: bool = False):
+    """pool: (L, num_blocks, ...) — copy blocks ``src[i] -> dst[i]`` along
+    axis 1, in place.  ``interpret=True`` runs the Pallas interpreter (CPU
+    tests); the jnp oracle is :func:`ref.migrate_blocks_ref`."""
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, chunk), lambda i, j, src_ref, dst_ref: (src_ref[i], j)),
-        ],
-        out_specs=pl.BlockSpec((1, chunk),
-                               lambda i, j, src_ref, dst_ref: (dst_ref[i], j)),
+        grid=(src.shape[0],),
+        in_specs=[any_space],
+        out_specs=any_space,
+        scratch_shapes=[pltpu.SemaphoreType.DMA],
     )
     return pl.pallas_call(
         _migrate_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        input_output_aliases={2: 0},  # x aliases the output: in-place move
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={2: 0},  # pool aliases the output: in-place move
         interpret=interpret,
-    )(src, dst, x)
-
-
-def migrate_blocks(pool, src, dst, *, use_kernel: bool = False,
-                   interpret: bool = True):
-    """pool: (L, num_blocks, ...) — copy blocks src->dst along axis 1.
-
-    use_kernel=False runs the pure-jnp oracle (the fast path on this CPU
-    container); use_kernel=True exercises the Pallas kernel (interpret mode
-    on CPU, compiled on TPU)."""
-    L, nb = pool.shape[:2]
-    rest = pool.shape[2:]
-    if not use_kernel:
-        return jnp.moveaxis(
-            migrate_blocks_ref(jnp.moveaxis(pool, 1, 0).reshape(nb, -1),
-                               src, dst).reshape((nb, L) + rest),
-            0, 1)
-    rows = jnp.moveaxis(pool, 1, 0).reshape(nb, -1)
-    row = rows.shape[1]
-    # pad row dim to a lane-aligned chunk multiple
-    chunk = min(_CHUNK, max(128, row))
-    pad = (-row) % chunk
-    if pad:
-        rows = jnp.pad(rows, ((0, 0), (0, pad)))
-    out = _migrate_rows_pallas(rows, src.astype(jnp.int32),
-                               dst.astype(jnp.int32), interpret=interpret)
-    out = out[:, :row].reshape((nb, L) + rest)
-    return jnp.moveaxis(out, 0, 1)
+        name="block_migration",
+    )(src.astype(jnp.int32), dst.astype(jnp.int32), pool)

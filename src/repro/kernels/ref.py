@@ -11,9 +11,10 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def migrate_blocks_ref(x, src, dst):
-    """x: (num_blocks, row); copy rows src -> dst (one-to-one)."""
-    return x.at[dst].set(x[src])
+def migrate_blocks_ref(pool, src, dst):
+    """pool: (L, num_blocks, ...); copy blocks src -> dst along axis 1
+    (one-to-one)."""
+    return pool.at[:, dst].set(pool[:, src])
 
 
 def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths):

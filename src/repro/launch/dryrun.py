@@ -22,16 +22,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# reuse compilations across dry-run invocations (same lowering -> cache hit)
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
-
 from .. import configs
 from ..configs.base import ShapeConfig, shapes_for
 from ..distributed import sharding as shd
 from ..models import registry
-from .mesh import make_production_mesh, mesh_context
+from .compile_cache import enable_compile_cache
+from .mesh import make_production_mesh
 
 # hardware constants (TPU v5e)
 PEAK_FLOPS = 197e12
@@ -261,7 +257,7 @@ def run_cell(arch: str, shape: ShapeConfig, mesh, *, spec_decode=False,
     fn, args, in_sh, out_sh, donate, meta = build_cell(
         arch, shape, mesh, spec_decode=spec_decode, cfg_override=cfg_override,
         accum_override=accum_override)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                          donate_argnums=donate)
         lowered = jitted.lower(*args)
@@ -345,6 +341,8 @@ def main():
                     help="lower the speculative (draft+verify) serve step")
     ap.add_argument("--json", default=None)
     args = ap.parse_args()
+    # reuse compilations across dry-run invocations (same lowering -> hit)
+    enable_compile_cache()
 
     meshes = []
     if args.both_meshes:

@@ -7,25 +7,16 @@ elastic scaling adds or removes pods without resharding the model axes.
 
 Defined as a FUNCTION so importing this module never touches jax device
 state (the dry-run must set XLA_FLAGS before any jax initialisation).
-
-The helpers paper over JAX API drift: ``axis_types``/``AxisType`` only
-exist on newer releases, and ``jax.set_mesh`` replaced the plain Mesh
-context manager — ``mesh_context`` returns whichever this version has.
+Scope a mesh for jit'd computations with ``jax.set_mesh(mesh)``.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    axis_type = getattr(getattr(jax, "sharding"), "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -37,12 +28,3 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_debug_mesh(data: int = 2, model: int = 2):
     """Small mesh for CPU multi-device tests (8 host devices)."""
     return _make_mesh((data, model), ("data", "model"))
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where available, else the Mesh context
-    manager — both scope the default mesh for jit'd computations."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh

@@ -1,9 +1,12 @@
 """Serving entrypoint.
 
-Real execution tier (reduced configs, actual JAX compute over the paged-KV
-runtime; --chunk-tokens also works here — chunked prefill runs on real
-execution through RealBackend.hybrid_step):
-  PYTHONPATH=src python -m repro.launch.serve --arch deepseek-7b --policy nightjar
+Real execution tier (actual JAX compute over the paged-KV runtime, the
+configuration as registered; --reduced swaps in tiny widths for a CPU run;
+--chunk-tokens also works here — chunked prefill runs on real execution
+through RealBackend.hybrid_step):
+  PYTHONPATH=src python -m repro.launch.serve --tier real --reduced \
+      --arch paper-7b --max-batch 4 --requests 8
+  (on one TPU v5e: --arch paper-7b-1chip, without --reduced)
 
 Analytical paper-scale tier (TPU v5e cost model):
   PYTHONPATH=src python -m repro.launch.serve --arch paper-7b --tier sim \
@@ -64,6 +67,10 @@ def main():
     ap.add_argument("--arch", default="paper-7b")
     ap.add_argument("--policy", default="nightjar")
     ap.add_argument("--tier", choices=["real", "sim"], default="sim")
+    ap.add_argument("--reduced", action="store_true",
+                    help="real tier: run configs.reduced of the pair (tiny "
+                         "widths, for the CPU) instead of the registered "
+                         "configuration")
     ap.add_argument("--rate", type=float, default=10.0)
     ap.add_argument("--requests", type=int, default=200)
     ap.add_argument("--dataset", default="sharegpt")
@@ -166,8 +173,12 @@ def main():
     if args.brownout and args.tier != "sim":
         ap.error("--brownout runs on the simulated tier only (the ladder "
                  "is driven by the shared virtual clock)")
+    if args.reduced and args.tier != "real":
+        ap.error("--reduced applies to --tier real only")
 
     from .. import configs
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     trace_rec = None
     if args.trace or args.metrics_out:
@@ -270,65 +281,25 @@ def main():
             engine = build_sim_engine(cfg, args.policy, trace=trace_rec)
             metrics = engine.run(reqs)
     else:
-        from ..core.bandits import make_policy
-        from ..models import registry
-        from ..serving.costmodel import RooflineCostModel, TPU_V5E
-        from ..serving.engine import ServingEngine
-        from ..serving.kv_cache import BlockManager
-        from ..serving.memory_manager import ElasticMemoryManager
-        from ..serving.paged_runtime import num_blocks_for
-        from ..serving.real_backend import RealBackend, make_real_backend
-        from ..serving.scheduler import ContinuousBatchingScheduler
+        from ..serving.real_backend import build_real_engine
         from ..serving.workload import tiny_requests
 
-        cfg = configs.reduced(configs.get_config(args.arch))
-        dcfg = configs.reduced(configs.get_draft_config(args.arch))
-        target, draft = registry.get_model(cfg), registry.get_model(dcfg)
         # the real tier has no wall-clock cost model: 'auto' falls back
         chunk = None
         if args.chunk_tokens != "0":
             chunk = 256 if args.chunk_tokens == "auto" else int(args.chunk_tokens)
-        # ONE BlockManager drives both scheduler admission and the physical
-        # paged pool, sized from the roofline HBM budget
-        cm = RooflineCostModel(TPU_V5E)
-        block_size = 8
-        nb = num_blocks_for(cm, cfg, dcfg, block_size, max_blocks=1024)
-        host_store = None
-        if args.kv_offload:
-            from ..serving.kv_cache import HostKVStore
-            host_store = HostKVStore(4 * nb)
-        bm = BlockManager(nb, block_size,
-                          prefix_caching=args.prefix_caching == "on",
-                          host_store=host_store)
-        backend = make_real_backend(target, draft, max_batch=4, max_seq=256,
-                                    seed=args.seed, block_manager=bm,
-                                    cost_model=cm)
-        sched = ContinuousBatchingScheduler(bm, max_batch=4,
-                                            chunk_tokens=chunk,
-                                            prefill_order=args.prefill_order)
-        memmgr = None
-        if not args.no_offload and isinstance(backend, RealBackend):
-            # offload-driven elastic expansion of the PHYSICAL paged pool:
-            # the draft's weight bytes converted to KV blocks, clamped so a
-            # tiny reduced model still exercises the grow/migrate path
-            draft_blocks = max(min(
-                -(-cm.weight_bytes(dcfg) // backend.tkv.bytes_per_block),
-                bm.total_blocks // 4), 1)
-            memmgr = ElasticMemoryManager(
-                bm, draft_blocks=int(draft_blocks),
-                offload_fn=backend.offload_draft,
-                reload_fn=backend.reload_draft,
-                migrate_fn=backend.migrate_pools,
-                grow_fn=backend.grow_pools,
-                shrink_fn=backend.shrink_pools)
-        engine = ServingEngine(backend, sched,
-                               make_policy(args.policy, 3, seed=args.seed),
-                               memmgr, gamma_max=3)
+        engine = build_real_engine(
+            args.arch, args.policy, reduced=args.reduced,
+            max_batch=args.max_batch, gamma_max=args.gamma_max,
+            chunk_tokens=chunk, prefix_caching=args.prefix_caching == "on",
+            prefill_order=args.prefill_order, kv_offload=args.kv_offload,
+            offload=not args.no_offload, seed=args.seed)
         if trace_rec is not None:
             engine.attach_trace(trace_rec)
         reqs = tiny_requests(min(args.requests, 16), rate_qps=args.rate,
                              prompt_len=16, output_len=16,
-                             vocab=cfg.vocab_size, seed=args.seed,
+                             vocab=engine.backend.target.cfg.vocab_size,
+                             seed=args.seed,
                              template_len=(8 if args.dataset == "templated"
                                            else 0))
         metrics = engine.run(reqs, max_steps=5000)

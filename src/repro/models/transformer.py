@@ -259,49 +259,56 @@ def prefill(cfg, params, batch, max_len: int):
 
 
 def init_paged_cache(cfg, num_blocks: int, block_size: int):
-    """Paged KV pool: (L, num_blocks + 1, block_size, KH, hd).
+    """Paged KV pool: (L, num_blocks + 1, block_size, KH * hd).
 
-    The LAST block (index ``num_blocks``) is the write-off ("trash") block:
-    padded batch rows and ragged-chunk tail slots scatter their K/V there so
-    no write can ever touch a live sequence's blocks.  Block tables are
-    padded with the trash id too, which doubles as the "any valid id"
-    padding the attention kernels require."""
+    Each token's heads sit side by side in the last axis, so a block is a
+    lane-dense (block_size, KH * hd) tile for every head size — the
+    block-migration kernel moves it with one DMA.  The LAST block (index
+    ``num_blocks``) is the write-off ("trash") block: padded batch rows and
+    ragged-chunk tail slots scatter their K/V there so no write can ever
+    touch a live sequence's blocks.  Block tables are padded with the trash
+    id too, which doubles as the "any valid id" padding the attention
+    kernels require."""
     dt = _dtype(cfg)
     KH, hd, L = cfg.num_kv_heads, cfg.resolved_head_dim, cfg.num_layers
-    shape = (L, num_blocks + 1, block_size, KH, hd)
+    shape = (L, num_blocks + 1, block_size, KH * hd)
     return {"k_pages": jnp.zeros(shape, dt), "v_pages": jnp.zeros(shape, dt)}
 
 
-def _paged_write(pages, new, tables, positions, valid):
-    """Scatter ``new`` (B, T, KH, hd) into ``pages`` (NB+1, bs, KH, hd) at
-    per-token ``positions`` (B, T) through the block tables; slots where
-    ``valid`` is False are routed to the trash block."""
-    bs = pages.shape[1]
+def _paged_write(pages, layer, new, tables, positions, valid):
+    """Scatter ``new`` (B, T, KH, hd) into layer ``layer`` of ``pages``
+    (L, NB+1, bs, KH * hd) at per-token ``positions`` (B, T) through the
+    block tables; slots where ``valid`` is False are routed to the trash
+    block.  The scatter updates the stacked pool in place."""
+    B, T = positions.shape
+    bs = pages.shape[2]
     maxb = tables.shape[1]
     idx = jnp.minimum(positions // bs, maxb - 1)
     blk = jnp.take_along_axis(tables, idx, axis=1)      # (B, T)
-    blk = jnp.where(valid, blk, pages.shape[0] - 1)     # trash for pad slots
+    blk = jnp.where(valid, blk, pages.shape[1] - 1)     # trash for pad slots
     off = positions % bs
-    return pages.at[blk, off].set(new.astype(pages.dtype))
+    return pages.at[layer, blk, off].set(
+        new.reshape(B, T, -1).astype(pages.dtype))
 
 
-def apply_layer_decode_paged(cfg, layer, h, k_pages, v_pages, tables,
-                             positions, valid, lengths, *, use_kernel=False):
-    """Paged analogue of :func:`apply_layer_decode`: h (B, T, d); pages
-    (NB+1, bs, KH, hd); new K/V are scattered through the block tables
-    first, then every query attends to all paged slots at or before its
-    position (the multi-query paged-attention kernel / its jnp oracle)."""
+def apply_layer_decode_paged(cfg, layer, h, pages, i, tables, positions,
+                             valid, lengths):
+    """Paged analogue of :func:`apply_layer_decode` for layer ``i``: h
+    (B, T, d); pages {"k_pages", "v_pages"} (L, NB+1, bs, KH * hd).  New
+    K/V are scattered through the block tables first, then every query
+    attends to all paged slots at or before its position (the multi-query
+    paged-attention kernel, or its jnp oracle off the TPU — ``kernels.ops``
+    decides)."""
+    from ..kernels import ops
     x = apply_norm(cfg, h, layer["ln1"])
     q, k, v = qkv_project(cfg, layer["attn"], x, positions)
-    k_pages = _paged_write(k_pages, k, tables, positions, valid)
-    v_pages = _paged_write(v_pages, v, tables, positions, valid)
-    if use_kernel:
-        from ..kernels.paged_attention import paged_attention
-        o = paged_attention(q, k_pages, v_pages, tables, lengths,
-                            interpret=True)
-    else:
-        from ..kernels.ref import paged_attention_ref
-        o = paged_attention_ref(q, k_pages, v_pages, tables, lengths)
+    kp = _paged_write(pages["k_pages"], i, k, tables, positions, valid)
+    vp = _paged_write(pages["v_pages"], i, v, tables, positions, valid)
+    heads = kp.shape[1:3] + k.shape[2:]                 # (NB+1, bs, KH, hd)
+    o = ops.paged_attention(
+        q, jax.lax.dynamic_index_in_dim(kp, i, keepdims=False).reshape(heads),
+        jax.lax.dynamic_index_in_dim(vp, i, keepdims=False).reshape(heads),
+        tables, lengths)
     h = h + attn_output(layer["attn"], o.astype(h.dtype))
     x = apply_norm(cfg, h, layer["ln2"])
     if cfg.moe_num_experts:
@@ -309,11 +316,11 @@ def apply_layer_decode_paged(cfg, layer, h, k_pages, v_pages, tables,
                             capacity_factor=max(cfg.moe_capacity_factor, 2.0))
     else:
         y = apply_mlp(cfg, layer["mlp"], x)
-    return h + y, k_pages, v_pages
+    return h + y, {"k_pages": kp, "v_pages": vp}
 
 
 def decode_step_paged(cfg, params, pages, tokens, tables, start, valid=None,
-                      *, use_kernel: bool = False):
+                      *, last_only: bool = False):
     """Extend T tokens per sequence against the paged KV pool.
 
     One function serves every real-backend shape: plain decode (T=1),
@@ -324,8 +331,11 @@ def decode_step_paged(cfg, params, pages, tokens, tables, start, valid=None,
     with the trash id; start: (B,) tokens already materialised per sequence;
     valid: (B,) count of real tokens per row (None = all T valid).  Invalid
     tail slots write their K/V to the trash block and produce garbage logits
-    — callers read logits at index ``valid - 1``.  Returns
-    (logits (B, T, V), pages)."""
+    — callers read logits at index ``valid - 1``.  The pages are carried
+    through the layer loop and updated in place.  Returns
+    (logits (B, T, V), pages); with ``last_only`` only the row at
+    ``valid - 1`` is unembedded and the logits are (B, 1, V) — a prefill
+    extension never materialises (B, T, V) float32 logits."""
     B, T = tokens.shape
     positions = start[:, None] + jnp.arange(T)[None, :]            # (B, T)
     if valid is None:
@@ -338,24 +348,22 @@ def decode_step_paged(cfg, params, pages, tokens, tables, start, valid=None,
         h = h + params["pos_embed"][positions]
 
     if cfg.scan_layers:
-        def body(hh, xs):
-            layer, kp, vp = xs
-            hh, kp, vp = apply_layer_decode_paged(
-                cfg, layer, hh, kp, vp, tables, positions, vmask, lengths,
-                use_kernel=use_kernel)
-            return hh, (kp, vp)
-        h, (ks, vs) = jax.lax.scan(
-            body, h, (params["layers"], pages["k_pages"], pages["v_pages"]))
-        pages = {"k_pages": ks, "v_pages": vs}
+        def body(carry, layer):
+            hh, pg, i = carry
+            hh, pg = apply_layer_decode_paged(cfg, layer, hh, pg, i, tables,
+                                              positions, vmask, lengths)
+            return (hh, pg, i + 1), None
+        (h, pages, _), _ = jax.lax.scan(
+            body, (h, pages, jnp.int32(0)), params["layers"])
     else:
-        ks_l, vs_l = [], []
         for i, layer in enumerate(params["layers"]):
-            h, kp, vp = apply_layer_decode_paged(
-                cfg, layer, h, pages["k_pages"][i], pages["v_pages"][i],
-                tables, positions, vmask, lengths, use_kernel=use_kernel)
-            ks_l.append(kp)
-            vs_l.append(vp)
-        pages = {"k_pages": jnp.stack(ks_l), "v_pages": jnp.stack(vs_l)}
+            h, pages = apply_layer_decode_paged(cfg, layer, h, pages, i,
+                                                tables, positions, vmask,
+                                                lengths)
+    if last_only:
+        last = T - 1 if valid is None else jnp.maximum(valid - 1, 0)
+        idx = jnp.broadcast_to(jnp.asarray(last), (B,))[:, None, None]
+        h = jnp.take_along_axis(h, idx, axis=1)                    # (B, 1, d)
     h = apply_norm(cfg, h, params["final_norm"])
     return unembed(cfg, params, h), pages
 

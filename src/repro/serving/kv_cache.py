@@ -885,17 +885,13 @@ class PhysicalKVPool:
         v = self.v[:, idx].reshape(self.shape[0], -1, *self.shape[3:])[:, :length]
         return k, v
 
-    def migrate(self, plan: MigrationPlan, *, use_kernel: bool = True) -> None:
+    def migrate(self, plan: MigrationPlan) -> None:
         """§6.4 step 3: vectorised block migration (kernel-backed)."""
         if not len(plan):
             return
-        from ..kernels import block_migration
-        src = jnp.asarray(plan.src, jnp.int32)
-        dst = jnp.asarray(plan.dst, jnp.int32)
-        self.k = block_migration.migrate_blocks(self.k, src, dst,
-                                                use_kernel=use_kernel)
-        self.v = block_migration.migrate_blocks(self.v, src, dst,
-                                                use_kernel=use_kernel)
+        from ..kernels import ops
+        self.k = ops.migrate_blocks(self.k, plan.src, plan.dst)
+        self.v = ops.migrate_blocks(self.v, plan.src, plan.dst)
 
     def spill_blocks(self, ids: Sequence[int]
                      ) -> Tuple[np.ndarray, np.ndarray]:

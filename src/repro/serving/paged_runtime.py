@@ -1,7 +1,7 @@
 """Paged-KV runtime state for the real-execution backend.
 
 One :class:`PagedKVRuntime` per model (target / draft) owns the physical
-``(L, num_blocks + 1, block_size, KH, hd)`` key/value page arrays (the last
+``(L, num_blocks + 1, block_size, KH * hd)`` key/value page arrays (the last
 block is the write-off "trash" block absorbing padded-slot writes) and the
 host-side per-sequence materialised lengths.  The logical layout — which
 sequence owns which blocks — lives in the existing :class:`BlockManager`;
@@ -16,12 +16,14 @@ donated through the jitted step functions so XLA updates them in place.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence as Seq, Tuple
+from typing import Dict, List, Optional, Sequence as Seq, Tuple
 
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels import ops
 from ..models.registry import ModelAPI
+from .costmodel import kv_bytes_per_token
 from .kv_cache import BlockManager, MigrationPlan
 from .request import Sequence
 
@@ -32,14 +34,26 @@ def bucket_size(n: int) -> int:
 
 
 def num_blocks_for(cost_model, target_cfg, draft_cfg, block_size: int, *,
-                   min_blocks: int = 64, max_blocks: int = 4096,
+                   min_blocks: int = 64, max_blocks: Optional[int] = None,
                    reserve_frac: float = 0.1) -> int:
-    """Size the physical pool from the roofline HBM budget: the tokens that
-    fit beside the weights (``RooflineCostModel.kv_capacity_tokens``) divided
-    into blocks, clamped to a sane range for the reduced-model real tier."""
-    toks = cost_model.kv_capacity_tokens(target_cfg, draft_cfg,
-                                         reserve_frac=reserve_frac)
-    return int(min(max(toks // block_size, min_blocks), max_blocks))
+    """Size the physical pool from the roofline HBM budget: the bytes left
+    beside both models' weights, over what one block costs in BOTH pools
+    (every block id holds a target page and a draft page) plus its share of
+    the elastic cycle's resize copy.  That copy is the peak of the cycle:
+    :meth:`PagedKVRuntime.shrink` slices one base-size page array (k or v)
+    of the larger pool while the grown pool, which by then holds the
+    offloaded draft's bytes as blocks, is still live.  The reserve covers
+    the step programs' temporaries.  At least ``min_blocks``; at most
+    ``max_blocks`` when given."""
+    hw = cost_model.hw
+    avail = (hw.hbm_bytes * hw.chips * (1 - reserve_frac)
+             - cost_model.weight_bytes(target_cfg)
+             - cost_model.weight_bytes(draft_cfg))
+    kv_t = kv_bytes_per_token(target_cfg, cost_model.dtype_bytes)
+    kv_d = kv_bytes_per_token(draft_cfg, cost_model.dtype_bytes)
+    per_block = block_size * (kv_t + kv_d + max(kv_t, kv_d) // 2)
+    nb = max(int(avail // per_block), min_blocks)
+    return nb if max_blocks is None else min(nb, max_blocks)
 
 
 class PagedKVRuntime:
@@ -60,8 +74,8 @@ class PagedKVRuntime:
     @property
     def bytes_per_block(self) -> int:
         k = self.pages["k_pages"]
-        L, _, bs, kh, hd = k.shape
-        return 2 * L * bs * kh * hd * k.dtype.itemsize  # k + v
+        L, _, bs, row = k.shape
+        return 2 * L * bs * row * k.dtype.itemsize  # k + v
 
     def transfer_bytes(self, n_blocks: int) -> int:
         """Wire bytes for migrating ``n_blocks`` of this pool between
@@ -72,30 +86,25 @@ class PagedKVRuntime:
     # ------------------------------------------------------------------
     # copy-on-write + elastic physical pool (§6.3/6.4 on the real tier)
     # ------------------------------------------------------------------
-    def apply_copies(self, src: Seq[int], dst: Seq[int], *,
-                     use_kernel: bool = False) -> None:
+    def apply_copies(self, src: Seq[int], dst: Seq[int]) -> None:
         """Execute block copies src[i] -> dst[i] on-device in ONE batched
-        block-migration launch (the CoW fork path and the §6.4 step-3 data
-        movement share the same kernel).  No host round-trip: the pages stay
-        on-device, only the int32 index vectors travel."""
+        block-migration launch per page array (the CoW fork path and the
+        §6.4 step-3 data movement share the same kernel).  No host
+        round-trip: the pages stay on-device and are updated in place, only
+        the int32 index vectors travel."""
         if not len(src):
             return
-        from ..kernels import block_migration
-        s = jnp.asarray(list(src), jnp.int32)
-        d = jnp.asarray(list(dst), jnp.int32)
         for key in ("k_pages", "v_pages"):
-            self.pages[key] = block_migration.migrate_blocks(
-                self.pages[key], s, d, use_kernel=use_kernel)
+            self.pages[key] = ops.migrate_blocks(self.pages[key], src, dst)
 
-    def apply_plan(self, plan: MigrationPlan, *, use_kernel: bool = False
-                   ) -> None:
+    def apply_plan(self, plan: MigrationPlan) -> None:
         """§6.4 step 3 on the physical paged pools."""
-        self.apply_copies(plan.src, plan.dst, use_kernel=use_kernel)
+        self.apply_copies(plan.src, plan.dst)
 
     def spill_blocks(self, ids: Seq[int]) -> Dict[str, np.ndarray]:
         """Device→host gather of whole blocks for the host KV offload tier:
         ONE batched index gather per page array (k/v), materialised to host
-        numpy.  Returned arrays have shape (L, n, block_size, KH, hd) with
+        numpy.  Returned arrays have shape (L, n, block_size, KH * hd) with
         the block axis second, so ``arr[:, i]`` is block ``ids[i]``'s
         payload for a single :class:`~.kv_cache.HostBlockRecord`."""
         idx = jnp.asarray(list(ids), jnp.int32)
@@ -108,7 +117,7 @@ class PagedKVRuntime:
         arrays — one batched index-vector scatter per pool, the same data
         movement shape as the block-migration path with the source staged
         from host memory.  ``payloads`` mirrors :meth:`spill_blocks`'s
-        (L, n, block_size, KH, hd) layout."""
+        (L, n, block_size, KH * hd) layout."""
         if not len(ids):
             return
         idx = jnp.asarray(list(ids), jnp.int32)
@@ -121,12 +130,15 @@ class PagedKVRuntime:
         ``extra_blocks``, keeping the trash block LAST.  The old trash slot
         is recycled as ordinary storage — its garbage content is never read
         because per-sequence lengths gate every attention read, and every
-        block is written before its positions become readable."""
-        def pad(x):
-            L, nb1, bs, kh, hd = x.shape
-            z = jnp.zeros((L, extra_blocks, bs, kh, hd), x.dtype)
-            return jnp.concatenate([x, z], axis=1)
-        self.pages = {k: pad(v) for k, v in self.pages.items()}
+        block is written before its positions become readable.  One array
+        grows at a time and is waited for before the next: dispatch is
+        asynchronous, so without the wait every old array would still be
+        live beside every new one."""
+        for key in list(self.pages):
+            x = self.pages.pop(key)
+            pad = [(0, 0)] * x.ndim
+            pad[1] = (0, extra_blocks)
+            self.pages[key] = jnp.pad(x, pad).block_until_ready()
         self.num_blocks += extra_blocks
         self.trash = self.num_blocks
 
@@ -134,9 +146,12 @@ class PagedKVRuntime:
         """§6.4 step 5 on the physical pool: trim to ``to_blocks`` + trash.
         Must run after the BlockManager committed its contraction (no table
         references an id >= to_blocks).  The surviving slot at index
-        ``to_blocks`` becomes the new trash block."""
+        ``to_blocks`` becomes the new trash block.  Like :meth:`grow`, one
+        array is trimmed and waited for at a time."""
         assert to_blocks <= self.num_blocks, (to_blocks, self.num_blocks)
-        self.pages = {k: v[:, :to_blocks + 1] for k, v in self.pages.items()}
+        for key in list(self.pages):
+            self.pages[key] = self.pages.pop(key)[
+                :, :to_blocks + 1].block_until_ready()
         self.num_blocks = to_blocks
         self.trash = to_blocks
 

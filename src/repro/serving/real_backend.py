@@ -9,10 +9,11 @@ Two implementations share the Backend protocol:
   verification, chunked prefill, eviction and completion touch only int32
   tables and sampled tokens — the cache tensors never travel and are never
   gathered, scattered or re-bucketed.  One multi-query paged-attention
-  kernel (Pallas on TPU, jnp oracle on this CPU container) serves plain
-  decode (T=1), speculative verify (T=gamma+1) and chunked-prefill appends
-  (T=chunk), so ``hybrid_step`` runs the chunked scheduler's mixed
-  chunk+decode batches on real execution end-to-end.
+  kernel (compiled Pallas on a TPU, its jnp oracle elsewhere — see
+  ``kernels.ops``) serves plain decode (T=1), speculative verify
+  (T=gamma+1), prefill and chunked-prefill appends (T=chunk), so
+  ``hybrid_step`` runs the chunked scheduler's mixed chunk+decode batches
+  on real execution end-to-end.
 
 * :class:`DenseSlotBackend` — the legacy dense slot-cache implementation
   (whole-cache gather/scatter per step, per-sequence Python prefill loop),
@@ -20,7 +21,9 @@ Two implementations share the Backend protocol:
   and not paged, and as the baseline for the dense-vs-paged equivalence
   tests and ``--only backend`` benchmarks.
 
-:func:`make_real_backend` picks the implementation per model family.
+:func:`make_real_backend` picks the implementation per model family;
+:func:`build_real_engine` assembles the whole real serving tier (backend,
+scheduler, planner, elastic memory manager) for one configuration.
 
 Latencies are wall-clock (block_until_ready) — this is what the planner
 learns from on this tier, and what the C_switch profiler measures.
@@ -53,8 +56,7 @@ def make_real_backend(target: ModelAPI, draft: ModelAPI, **kw):
     per sequence and lives in fixed slots)."""
     if target.supports_paged and draft.supports_paged:
         return RealBackend(target, draft, **kw)
-    for k in ("block_manager", "num_blocks", "block_size", "cost_model",
-              "use_kernel"):
+    for k in ("block_manager", "num_blocks", "block_size"):
         kw.pop(k, None)
     return DenseSlotBackend(target, draft, **kw)
 
@@ -69,17 +71,16 @@ class RealBackend:
 
     When ``block_manager`` is the scheduler's own instance, the scheduler's
     logical admission decisions and the physical pool are one and the same
-    object (the intended wiring — see ``launch/serve.py``).  Without one, a
-    private BlockManager sized for ``max_batch x max_seq`` (or from
-    ``cost_model.kv_capacity_tokens``) is created and mirrored internally.
+    object (the intended wiring — see :func:`build_real_engine`).  Without
+    one, a private BlockManager sized for ``max_batch x max_seq`` is created
+    and mirrored internally.
     """
 
     def __init__(self, target: ModelAPI, draft: ModelAPI, *,
                  max_batch: int = 8, max_seq: int = 256, seed: int = 0,
                  sampling: str = "greedy", temperature: float = 1.0,
                  block_manager: Optional[BlockManager] = None,
-                 block_size: int = 8, num_blocks: Optional[int] = None,
-                 cost_model=None, use_kernel: bool = False):
+                 block_size: int = 8, num_blocks: Optional[int] = None):
         if not (target.supports_paged and draft.supports_paged):
             raise NotImplementedError(
                 "RealBackend is the paged-KV runtime; use make_real_backend "
@@ -92,24 +93,21 @@ class RealBackend:
         self.key = jax.random.PRNGKey(seed)
 
         k1, k2 = jax.random.split(jax.random.PRNGKey(seed + 1))
-        self.tparams = target.init(k1)
-        self.dparams = draft.init(k2)
+        # jitted init draws each weight straight into its dtype: eager init
+        # would hold a float32 copy of the largest stacked weight beside it
+        self.tparams = jax.jit(target.init)(k1)
+        self.dparams = jax.jit(draft.init)(k2)
         self.dparams_host: Optional[dict] = None  # offloaded copy
 
         if block_manager is None:
             if num_blocks is None:
-                if cost_model is not None:
-                    num_blocks = num_blocks_for(cost_model, target.cfg,
-                                                draft.cfg, block_size)
-                else:
-                    num_blocks = (-(-max_batch * max_seq // block_size)
-                                  + 2 * max_batch)
+                num_blocks = (-(-max_batch * max_seq // block_size)
+                              + 2 * max_batch)
             block_manager = BlockManager(num_blocks, block_size)
             self._owns_bm = True
         else:
             self._owns_bm = False
         self.bm = block_manager
-        self.use_kernel = use_kernel
         self.tkv = PagedKVRuntime(target, self.bm)
         self.dkv = PagedKVRuntime(draft, self.bm)
 
@@ -132,10 +130,8 @@ class RealBackend:
             valid position (batched prefill / chunked-prefill appends fused
             with T=1 decode rows)."""
             logits, pages = target.decode_step_paged(
-                params, pages, tokens, tables, start, valid,
-                use_kernel=use_kernel)
-            idx = jnp.maximum(valid - 1, 0)[:, None, None]
-            lg = jnp.take_along_axis(logits, idx, axis=1)[:, 0] / temperature
+                params, pages, tokens, tables, start, valid, last_only=True)
+            lg = logits[:, 0] / temperature
             if sampling == "greedy":
                 nxt = jnp.argmax(lg, axis=-1)
             else:
@@ -144,8 +140,7 @@ class RealBackend:
 
         def _extend_draft(params, pages, tokens, tables, start, valid):
             _, pages = draft.decode_step_paged(params, pages, tokens, tables,
-                                               start, valid,
-                                               use_kernel=use_kernel)
+                                               start, valid, last_only=True)
             return pages
 
         self._extend_t = jax.jit(_extend_target,
@@ -250,8 +245,8 @@ class RealBackend:
             return
         src = [c[0] for c in copies]
         dst = [c[1] for c in copies]
-        self.tkv.apply_copies(src, dst, use_kernel=self.use_kernel)
-        self.dkv.apply_copies(src, dst, use_kernel=self.use_kernel)
+        self.tkv.apply_copies(src, dst)
+        self.dkv.apply_copies(src, dst)
 
     def reserve(self, seqs: List[Sequence], gamma: int) -> List[Sequence]:
         """Grow block tables to cover this step's gamma+1 KV writes BEFORE
@@ -296,8 +291,8 @@ class RealBackend:
         targets and before ``shrink_pools`` trims the high region."""
         self.apply_host_transfers()
         t0 = time.perf_counter()
-        self.tkv.apply_plan(plan, use_kernel=self.use_kernel)
-        self.dkv.apply_plan(plan, use_kernel=self.use_kernel)
+        self.tkv.apply_plan(plan)
+        self.dkv.apply_plan(plan)
         jax.block_until_ready(self.tkv.pages["k_pages"])
         jax.block_until_ready(self.dkv.pages["k_pages"])
         return time.perf_counter() - t0
@@ -567,6 +562,80 @@ class RealBackend:
 
     def output_tokens(self, req_id: int) -> List[int]:
         return self.tokens_out.get(req_id, [])
+
+
+# ---------------------------------------------------------------------------
+# The real serving tier, assembled
+# ---------------------------------------------------------------------------
+
+# KV block size of the real tier: the bf16 sublane tile
+BLOCK_SIZE = 16
+# positions per slot of DenseSlotBackend (SSM/hybrid/encdec pairs)
+DENSE_MAX_SEQ = 256
+
+
+def build_real_engine(arch: str, policy: str = "nightjar", *,
+                      reduced: bool = False, max_batch: int = 8,
+                      gamma_max: int = 5, chunk_tokens: Optional[int] = None,
+                      prefix_caching: bool = False,
+                      prefill_order: str = "fifo", kv_offload: bool = False,
+                      offload: bool = True, seed: int = 0):
+    """The real serving tier for the registered pair ``arch``: the backend
+    :func:`make_real_backend` picks for its family over one
+    :class:`BlockManager` shared with the scheduler, the ``policy`` planner
+    and, with ``offload``, the :class:`ElasticMemoryManager` wired to the
+    physical pools.  For a paged pair (:class:`RealBackend`) the pool is
+    sized from the roofline HBM budget of one TPU v5e; for a dense-slot
+    pair it meters admission over the slots' ``max_batch x DENSE_MAX_SEQ``
+    positions, and there is no elastic memory manager.
+
+    The configuration runs as registered; ``reduced`` swaps in
+    ``configs.reduced`` of both models (CPU runs and tests).  Returns the
+    :class:`ServingEngine`; its ``backend`` and ``memmgr`` are the parts."""
+    from .. import configs
+    from ..core.bandits import make_policy
+    from ..models import registry
+    from .costmodel import RooflineCostModel, TPU_V5E
+    from .engine import ServingEngine
+    from .kv_cache import HostKVStore
+    from .memory_manager import ElasticMemoryManager
+    from .scheduler import ContinuousBatchingScheduler
+
+    cfg, dcfg = configs.get_config(arch), configs.get_draft_config(arch)
+    if reduced:
+        cfg, dcfg = configs.reduced(cfg), configs.reduced(dcfg)
+    target, draft = registry.get_model(cfg), registry.get_model(dcfg)
+    if target.supports_paged and draft.supports_paged:
+        # a reduced pair's budget would be thousands of blocks its CPU
+        # workloads never touch (and every interpret-mode kernel call walks)
+        nb = num_blocks_for(RooflineCostModel(TPU_V5E), cfg, dcfg,
+                            BLOCK_SIZE, max_blocks=256 if reduced else None)
+    else:
+        nb = -(-max_batch * DENSE_MAX_SEQ // BLOCK_SIZE)
+    bm = BlockManager(nb, BLOCK_SIZE, prefix_caching=prefix_caching,
+                      host_store=HostKVStore(4 * nb) if kv_offload else None)
+    backend = make_real_backend(target, draft, max_batch=max_batch,
+                                max_seq=DENSE_MAX_SEQ, seed=seed,
+                                block_manager=bm)
+    sched = ContinuousBatchingScheduler(bm, max_batch=max_batch,
+                                        chunk_tokens=chunk_tokens,
+                                        prefill_order=prefill_order)
+    memmgr = None
+    if offload and isinstance(backend, RealBackend):
+        # §6.3: the offloaded draft's weight bytes become blocks of BOTH
+        # pools, since grow_pools extends the target and the draft pool
+        per_block = backend.tkv.bytes_per_block + backend.dkv.bytes_per_block
+        memmgr = ElasticMemoryManager(
+            bm, draft_blocks=max(-(-registry.param_bytes(dcfg) // per_block),
+                                 1),
+            offload_fn=backend.offload_draft,
+            reload_fn=backend.reload_draft,
+            migrate_fn=backend.migrate_pools,
+            grow_fn=backend.grow_pools,
+            shrink_fn=backend.shrink_pools)
+    return ServingEngine(backend, sched,
+                         make_policy(policy, gamma_max, seed=seed), memmgr,
+                         gamma_max=gamma_max)
 
 
 # ---------------------------------------------------------------------------

@@ -110,3 +110,17 @@ def test_shapes_assignment():
             assert "long_500k" in names
         total += 4  # each arch is assigned 4 cells (skips documented)
     assert total == 40
+
+
+def test_paper_7b_one_chip_cut_keeps_published_widths():
+    """The one-chip cut of the paper pair changes the target's depth and
+    its name, nothing else; the draft is the published draft."""
+    full, cut = configs.get_config("paper-7b"), configs.get_config(
+        "paper-7b-1chip")
+    assert cut.num_layers == 14 and full.num_layers == 28
+    assert cut.name != full.name       # the cost model caches by name
+    assert cut == full.replace(name=cut.name, num_layers=14)
+    assert (configs.get_draft_config("paper-7b-1chip")
+            == configs.get_draft_config("paper-7b"))
+    assert "paper-7b-1chip" in configs.list_archs()
+    assert "paper-7b-1chip" not in configs.ASSIGNED_ARCHS

@@ -22,7 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs
 from repro.models import registry
 from repro.distributed import sharding as shd
-from repro.launch.mesh import make_debug_mesh, mesh_context
+from repro.launch.mesh import make_debug_mesh
 
 mesh = make_debug_mesh(4, 2)
 out = {}
@@ -46,7 +46,7 @@ def loss_fn(p, b):
 
 ref = float(loss_fn(params, batch))
 
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     b_sh = shd.to_named(shd.data_specs(cfg, batch, mesh), mesh)
     f = jax.jit(loss_fn, in_shardings=(p_sh, b_sh),
                 out_shardings=NamedSharding(mesh, P()))
@@ -62,7 +62,7 @@ out["got"] = got
 # 2. decode with context-parallel KV (seq over model) matches 1-device
 _, cache = api.prefill(params, {"tokens": tok}, 64)
 lg_ref, _ = api.decode_step(params, cache, tok[:, :1])
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     c_sh = shd.to_named(shd.cache_specs(cfg, cache, mesh), mesh)
     t_sh = shd.to_named(shd.token_specs(tok[:, :1], mesh), mesh)
     g = jax.jit(lambda p, c, t: api.decode_step(p, c, t),

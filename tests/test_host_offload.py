@@ -388,7 +388,7 @@ def test_randomized_spill_restore_soak():
             plan = bm.plan_contraction()
             if plan is not None:
                 _flush(bm, pool)         # capture plan-time spills FIRST
-                pool.migrate(plan, use_kernel=False)
+                pool.migrate(plan)
                 bm.commit_contraction(plan)
                 pool.shrink(bm.total_blocks)
             # plan can legitimately fail under load (not enough low free

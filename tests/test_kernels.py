@@ -11,22 +11,25 @@ from repro.kernels.paged_attention import paged_attention
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("nb,row_shape", [(8, (4, 2, 4)), (16, (2, 8, 16)),
-                                          (5, (3, 2, 2))])
-def test_block_migration_sweep(dtype, nb, row_shape):
+@pytest.mark.parametrize("nb,row_shape,m", [
+    (8, (4, 2, 4), 4), (16, (2, 8, 16), 8), (5, (3, 2, 2), 2),
+    # the paged pools' (L, NB+1, block_size, KH * hd) layout: draft and
+    # target rows of the 7b pair; m=3 pads the map to 4 entries
+    (9, (16, 128), 3), (12, (16, 512), 5)])
+def test_block_migration_sweep(dtype, nb, row_shape, m):
     key = jax.random.PRNGKey(0)
     L = 3
     x = jax.random.normal(key, (L, nb) + row_shape).astype(dtype)
-    m = max(nb // 2, 1)
-    src = jnp.asarray(np.random.default_rng(1).choice(nb, m, replace=False),
-                      jnp.int32)
-    free = [i for i in range(nb) if i not in np.asarray(src)]
-    dst = jnp.asarray(free[:m], jnp.int32)
-    a = migrate_blocks(x, src, dst, use_kernel=False)
-    b = migrate_blocks(x, src, dst, use_kernel=True, interpret=True)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    np.testing.assert_array_equal(np.asarray(a[:, np.asarray(dst)]),
-                                  np.asarray(x[:, np.asarray(src)]))
+    src = np.random.default_rng(1).choice(nb, m, replace=False)
+    dst = np.asarray([i for i in range(nb) if i not in src][:m])
+    want = np.asarray(ref.migrate_blocks_ref(x, src, dst))
+    np.testing.assert_array_equal(want[:, dst], np.asarray(x[:, src]))
+    got = migrate_blocks(x, jnp.asarray(src), jnp.asarray(dst),
+                         interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    with ops.interpret_kernels():     # dispatch + power-of-two padding
+        got = ops.migrate_blocks(x, src, dst)   # donates x
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -51,7 +54,8 @@ def test_paged_attention_sweep(B, H, KH, D, bs, maxb, dtype):
                                np.asarray(want, np.float32), atol=atol)
 
 
-@pytest.mark.parametrize("T", [1, 4, 8])
+# T * G > MAX_ROWS spans several query-row tiles (the last one padded)
+@pytest.mark.parametrize("T", [1, 4, 8, 160])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("B,H,KH,D,bs,maxb", [
     (2, 4, 4, 64, 16, 3),    # MHA
@@ -60,8 +64,10 @@ def test_paged_attention_sweep(B, H, KH, D, bs, maxb, dtype):
 ])
 def test_paged_attention_multiquery_sweep(B, H, KH, D, bs, maxb, dtype, T):
     """Multi-query extension (T=1 decode / T=gamma+1 verify / T=chunk
-    append) vs the jnp oracle, over GQA ratios and ragged lengths."""
+    append or prefill) vs the jnp oracle, over GQA ratios and ragged
+    lengths."""
     key = jax.random.PRNGKey(7)
+    maxb = max(maxb, -(-(T + 40) // bs))     # room for T plus a context
     nblocks = maxb * B + 2
     ks = jax.random.split(key, 4)
     q = jax.random.normal(ks[0], (B, T, H, D)).astype(dtype)

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import ops
 from repro.serving.kv_cache import (BlockManager, OutOfBlocks,
                                     PhysicalKVPool)
 
@@ -143,7 +144,8 @@ def test_migration_preserves_logical_contents():
     bm.release(1)               # free low blocks so contraction has room
     plan = bm.plan_contraction()
     assert plan is not None and len(plan) > 0
-    pool.migrate(plan, use_kernel=True)   # Pallas kernel (interpret mode)
+    with ops.interpret_kernels():         # Pallas kernel (interpret mode)
+        pool.migrate(plan)
     bm.commit_contraction(plan)
     pool.shrink(bm.base_blocks)
     bm.check_invariants()

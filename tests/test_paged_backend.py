@@ -16,8 +16,10 @@ import pytest
 
 from repro import configs
 from repro.core.bandits import make_policy
+from repro.kernels import ops
 from repro.models import registry
-from repro.serving.costmodel import RTX_4090, RooflineCostModel
+from repro.serving.costmodel import (RTX_4090, RooflineCostModel,
+                                     kv_bytes_per_token)
 from repro.serving.engine import ServingEngine
 from repro.serving.kv_cache import BlockManager, OutOfBlocks
 from repro.serving.paged_runtime import PagedKVRuntime, num_blocks_for
@@ -157,7 +159,8 @@ def test_cow_fork_copy_preserves_logits():
     assert matched == P
     bm.share(2, blocks, P - 1)
     (src, dst), = bm.fork_for_write(2, P - 1, P)
-    rt.apply_copies(*zip(*bm.drain_pending_copies()), use_kernel=True)
+    with ops.interpret_kernels():
+        rt.apply_copies(*zip(*bm.drain_pending_copies()))
     tbl2 = jnp.asarray([bm.tables[2]], jnp.int32)
     # recompute the last prompt token into the PRIVATE copy
     lg2, rt.pages = api.decode_step_paged(params, rt.pages, tok[:, -1:],
@@ -220,11 +223,19 @@ def test_num_blocks_for_sizes_pool_from_roofline_budget():
     cm = RooflineCostModel(RTX_4090)
     t = configs.get_config("paper-7b")
     d = configs.get_draft_config("paper-7b")
-    nb = num_blocks_for(cm, t, d, 16, max_blocks=10**9)
-    assert nb == cm.kv_capacity_tokens(t, d) // 16
+    nb = num_blocks_for(cm, t, d, 16)
+    # the bytes beside both weights, over one block of BOTH pools plus one
+    # target page array's block (the resize copy of the elastic cycle)
+    per_block = 16 * (kv_bytes_per_token(t) + kv_bytes_per_token(d)
+                      + kv_bytes_per_token(t) // 2)
+    avail = (RTX_4090.hbm_bytes * 0.9 - cm.weight_bytes(t)
+             - cm.weight_bytes(d))
+    assert nb == int(avail // per_block)
+    assert nb < cm.kv_capacity_tokens(t, d) // 16   # target pool alone
     assert num_blocks_for(cm, t, d, 16, max_blocks=512) == 512  # clamped
     tiny = configs.reduced(t)
-    assert num_blocks_for(cm, tiny, configs.reduced(d), 8) == 4096
+    assert num_blocks_for(cm, tiny, configs.reduced(d), 8,
+                          max_blocks=4096) == 4096
 
 
 def test_runtime_batch_tables_pad_with_trash():
@@ -263,7 +274,7 @@ def test_runtime_grow_shrink_tracks_block_manager():
     assert rt.trash == 12
     assert rt.pages["k_pages"].shape[1] == 13
     # pre-existing content survives the grow
-    assert float(rt.pages["k_pages"][0, 3, 0, 0, 0]) == 7.0
+    assert float(rt.pages["k_pages"][0, 3, 0, 0]) == 7.0
 
     # a sequence landing entirely in the expanded region (the free list
     # pops the freshly attached high ids first) round-trips batch_tables
@@ -287,7 +298,7 @@ def test_runtime_grow_shrink_tracks_block_manager():
     assert rt.num_blocks == bm.total_blocks == 8 and rt.trash == 8
     assert all(b < bm.boundary for b in bm.tables[2])
     moved = bm.tables[2][0]                         # high[0]'s new home
-    assert float(rt.pages["k_pages"][0, moved, 0, 0, 0]) == 3.0
+    assert float(rt.pages["k_pages"][0, moved, 0, 0]) == 3.0
     bm.check_invariants()
 
 
@@ -346,6 +357,23 @@ def test_make_real_backend_selects_by_family():
                       DenseSlotBackend)
     with pytest.raises(NotImplementedError):
         RealBackend(t, d, max_batch=2, max_seq=32)
+
+
+@pytest.mark.parametrize("arch", ["mamba2-780m", "zamba2-1.2b"])
+def test_build_real_engine_serves_dense_slot_families(arch):
+    """The real tier's builder hands a pair without a paged-KV path to
+    DenseSlotBackend (no elastic memory manager) and serves it."""
+    from repro.serving.real_backend import build_real_engine
+    from repro.serving.workload import tiny_requests
+    engine = build_real_engine(arch, reduced=True, max_batch=2, gamma_max=2)
+    assert isinstance(engine.backend, DenseSlotBackend)
+    assert engine.memmgr is None
+    reqs = tiny_requests(3, rate_qps=100.0, prompt_len=8, output_len=6,
+                         vocab=engine.backend.target.cfg.vocab_size, seed=0)
+    m = engine.run(reqs, max_steps=500)
+    assert {r.req_id for r in m.requests} == {r.req_id for r in reqs}
+    for r in reqs:
+        assert len(engine.backend.output_tokens(r.req_id)) >= r.output_len
 
 
 # ---------------------------------------------------------------------------
