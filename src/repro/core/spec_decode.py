@@ -185,23 +185,28 @@ def make_paged_spec_step(target: ModelAPI, draft: ModelAPI, *,
                 nxt = jax.random.categorical(k, lg)
             return (dpg, nxt, pos + 1), (nxt, jax.nn.softmax(lg, axis=-1))
 
-        keys = jax.random.split(kd, gamma + 1)
-        (dpages, _, _), (toks, probs) = jax.lax.scan(
-            body, (dpages, last_tokens, lengths), keys)
-        draft_tokens = toks[:gamma].T                     # (B, g)
-        draft_probs = jnp.swapaxes(probs[:gamma], 0, 1)   # (B, g, V)
+        # named scopes tag each device op with its phase (profiler
+        # metadata only: they change no computation)
+        with jax.named_scope("draft"):
+            keys = jax.random.split(kd, gamma + 1)
+            (dpages, _, _), (toks, probs) = jax.lax.scan(
+                body, (dpages, last_tokens, lengths), keys)
+            draft_tokens = toks[:gamma].T                     # (B, g)
+            draft_probs = jnp.swapaxes(probs[:gamma], 0, 1)   # (B, g, V)
 
-        # target verifies [last, d_1..d_g] in one paged extension pass
-        chunk = jnp.concatenate([last_tokens[:, None], draft_tokens], axis=1)
-        t_logits, tpages = target.decode_step_paged(tparams, tpages, chunk,
-                                                    tables, lengths)
-        t_logits = t_logits / temperature
+        with jax.named_scope("verify"):
+            # target verifies [last, d_1..d_g] in one paged extension pass
+            chunk = jnp.concatenate([last_tokens[:, None], draft_tokens],
+                                    axis=1)
+            t_logits, tpages = target.decode_step_paged(
+                tparams, tpages, chunk, tables, lengths)
+            t_logits = t_logits / temperature
 
-        if sampling == "greedy":
-            res = verify_greedy(draft_tokens, t_logits)
-        else:
-            res = verify_rejection(kv, draft_tokens, draft_probs,
-                                   jax.nn.softmax(t_logits, -1))
+            if sampling == "greedy":
+                res = verify_greedy(draft_tokens, t_logits)
+            else:
+                res = verify_rejection(kv, draft_tokens, draft_probs,
+                                       jax.nn.softmax(t_logits, -1))
         n_acc = res["n_accepted"]
         return SpecResult(
             tokens=res["tokens"],
@@ -219,6 +224,7 @@ def make_paged_ar_step(target: ModelAPI, *, sampling: str = "greedy",
                        temperature: float = 1.0):
     """Plain autoregressive decode step over the paged pool (gamma=0 arm)."""
 
+    @jax.named_scope("decode")
     def ar_step(key, tparams, tpages, tables, lengths, last_tokens):
         logits, tpages = target.decode_step_paged(tparams, tpages,
                                                   last_tokens[:, None],

@@ -152,9 +152,15 @@ def main():
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="flight recorder: write a structured trace of the "
                          "run (request lifecycle spans, engine steps, fleet "
-                         "events) to PATH — deterministic, so same seed => "
-                         "byte-identical file (analyse with "
-                         "benchmarks/trace_report.py)")
+                         "events) to PATH.  Sim tier: on the virtual clock, "
+                         "deterministic, so same seed => byte-identical file "
+                         "(analyse with benchmarks/trace_report.py).  Real "
+                         "tier: on the recorder's wall clock, with the "
+                         "nightjar.* host spans (admit, plan, reserve, "
+                         "prefill/decode, prepare, device_wait, readback, "
+                         "commit), which also go into a running "
+                         "jax.profiler trace; its nightjar.clock span maps "
+                         "the file onto the profiler's timeline")
     ap.add_argument("--trace-format", choices=["jsonl", "chrome"],
                     default="jsonl",
                     help="trace file format: jsonl (trace_report.py input) "
@@ -183,7 +189,7 @@ def main():
     trace_rec = None
     if args.trace or args.metrics_out:
         from ..serving.observability import TraceRecorder
-        trace_rec = TraceRecorder()
+        trace_rec = TraceRecorder(wall=args.tier == "real")
 
     if args.tier == "sim":
         from ..serving.costmodel import (A100_40G, RTX_4090,

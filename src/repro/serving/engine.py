@@ -46,6 +46,7 @@ from typing import List, Optional, Protocol, Sequence as Seq
 from ..core.bandits import Policy
 from .kv_cache import OutOfBlocks
 from .memory_manager import ElasticMemoryManager
+from .observability import span
 from .request import Metrics, Request, Sequence
 from .scheduler import ContinuousBatchingScheduler
 
@@ -105,8 +106,10 @@ class ServingEngine:
         self.record_timeline = False
         # observability seam (serving/observability.py): attach_trace wires
         # a TraceRecorder through the scheduler/block-manager; None (the
-        # default) keeps every hook a single attribute check
+        # default) keeps every hook a single attribute check; ``spans`` is
+        # the same recorder in wall mode (host spans), else None
         self.trace = None
+        self.spans = None
         self._memmgr_traced = 0    # memmgr.events already copied to trace
         self._pending: List = []   # heap of (arrival, req_id, Request)
         # incoming prefilled requests migrating from a prefill-pool replica
@@ -132,8 +135,17 @@ class ServingEngine:
         and the seams that emit events below it (scheduler preemptions,
         block-manager spill/restore).  The context closures read the LIVE
         clock/replica-id, so a cluster may attach before assigning replica
-        ids.  ``None`` detaches everything."""
+        ids.  A wall-mode recorder also gets the host spans of the engine,
+        the backend and the memory manager, and its ``nightjar.clock``
+        mark.  ``None`` detaches everything."""
         self.trace = trace
+        self.spans = (trace if trace is not None and trace.enabled
+                      and trace.wall else None)
+        for part in (self.backend, self.memmgr):
+            if hasattr(part, "trace"):
+                part.trace = self.spans
+        if self.spans is not None:
+            self.spans.mark_clock()
         ctx = (lambda: (self.clock, self.replica_id)) \
             if trace is not None else None
         self.scheduler.trace = trace
@@ -497,16 +509,24 @@ class ServingEngine:
         reserve = getattr(self.backend, "reserve", None)
         if reserve is None or not seqs:
             return seqs
-        while seqs:
-            failed = reserve(seqs, gamma)
-            if not failed:
-                break
-            # preempt ONE victim (lowest class then youngest among the
-            # failed, matching the recompute policy) and retry: its
-            # released blocks often cover the rest
-            victim = max(failed, key=self.scheduler._age_key)
-            self.scheduler.preempt(victim)
-            seqs = [s for s in seqs if s in self.scheduler.running]
+        hs = self.spans
+        with span(hs, "nightjar.reserve") as sp:
+            n = len(seqs)
+            while seqs:
+                failed = reserve(seqs, gamma)
+                if not failed:
+                    break
+                # preempt ONE victim (lowest class then youngest among the
+                # failed, matching the recompute policy) and retry: its
+                # released blocks often cover the rest
+                victim = max(failed, key=self.scheduler._age_key)
+                self.scheduler.preempt(victim)
+                seqs = [s for s in seqs if s in self.scheduler.running]
+            if sp is not None:
+                sp.set(preempted=n - len(seqs))
+                hs.registry.counter(
+                    "preemptions_total",
+                    "sequences preempted to reserve KV").inc(n - len(seqs))
         return seqs
 
     def _drain_host_transfers(self) -> float:
@@ -642,25 +662,59 @@ class ServingEngine:
             "waiting": self.scheduler.num_waiting,
         })
 
+    def _select(self, hs, B: int, delta_max: int) -> int:
+        """The planner's gamma for this step (a ``nightjar.plan`` span)."""
+        with span(hs, "nightjar.plan") as sp:
+            gamma = self.policy.select(B, delta_max=delta_max)
+            if sp is not None:
+                sp.set(gamma=gamma)
+        return gamma
+
+    def _observe(self, hs, B: int, gamma: int, latency: float,
+                 committed: int, delta_max: int) -> None:
+        """Feed the step's latency per token back to the planner (a
+        ``nightjar.plan`` span)."""
+        with span(hs, "nightjar.plan", gamma=gamma):
+            self.policy.observe(B, gamma, latency / committed,
+                                n_accepted=(committed - B) / max(B, 1)
+                                if gamma else None,
+                                delta_max=delta_max)
+
     # ------------------------------------------------------------------
     def step(self, now: Optional[float] = None) -> Optional[StepReport]:
         """Advance the engine by one iteration of the Figure-4 loop."""
-        if self.scheduler.chunk_tokens is not None:
-            return self._step_hybrid(now)
+        run = (self._step_hybrid if self.scheduler.chunk_tokens is not None
+               else self._step_monolithic)
+        hs = self.spans
+        if hs is None:
+            return run(now)
+        with hs.engine_step() as sp:
+            rep = run(now)
+            if rep is not None:
+                sp.set(batch=rep.batch, gamma=rep.gamma, tokens=rep.tokens,
+                       admitted=rep.admitted)
+        return rep
+
+    def _step_monolithic(self, now: Optional[float]) -> Optional[StepReport]:
+        """One iteration with whole-prompt prefill (no chunk budget)."""
         if now is not None and now > self.clock:
             self.clock = now
         m = self.metrics
         t_start = self.clock
 
+        tr = self._tracer()
+        hs = self.spans
         # 1. arrivals up to now; reap expired deadlines BEFORE dispatch so
         #    a dead-on-arrival request never consumes prefill compute
-        self._drain_arrivals()
-        reaped = self._reap_expired()
-
-        draft_ok = self.memmgr.can_speculate(self.clock) if self.memmgr else True
-        tr = self._tracer()
-
-        admitted = self.scheduler.schedule()
+        with span(hs, "nightjar.admit") as sp:
+            self._drain_arrivals()
+            reaped = self._reap_expired()
+            draft_ok = (self.memmgr.can_speculate(self.clock) if self.memmgr
+                        else True)
+            admitted = self.scheduler.schedule()
+            if sp is not None:
+                sp.set(admitted=len(admitted),
+                       waiting=self.scheduler.num_waiting)
         if admitted:
             t_prefill0 = self.clock
             t = self.backend.prefill(admitted, with_draft=draft_ok)
@@ -694,10 +748,11 @@ class ServingEngine:
 
         # 2. elastic memory triggers
         if self.memmgr is not None:
-            self.memmgr.step(
-                self.clock,
-                spec_disabled=(self.prev_gamma_effective == 0),
-                waiting=self.scheduler.num_waiting)
+            with span(hs, "nightjar.memmgr"):
+                self.memmgr.step(
+                    self.clock,
+                    spec_disabled=(self.prev_gamma_effective == 0),
+                    waiting=self.scheduler.num_waiting)
             draft_ok = self.memmgr.can_speculate(self.clock)
             if tr is not None:
                 self._trace_memmgr(tr)
@@ -705,7 +760,7 @@ class ServingEngine:
         # 3. arm selection (brownout stage >= spec_off forces gamma -> 0
         #    fleet-wide — the paper's MAB-disable recast as overload control)
         if draft_ok and not self.spec_forced_off:
-            gamma = self.policy.select(B, delta_max=delta_max)
+            gamma = self._select(hs, B, delta_max)
         else:
             gamma = 0
 
@@ -734,17 +789,17 @@ class ServingEngine:
         self.clock += out.latency
         total_committed = int(sum(out.n_committed))
 
-        finished, clipped = self._commit_decode(running, out.n_committed,
-                                                gamma)
-        total_committed -= clipped  # best-effort cap: tokens never written
+        with span(hs, "nightjar.commit") as sp:
+            finished, clipped = self._commit_decode(running, out.n_committed,
+                                                    gamma)
+            total_committed -= clipped  # best-effort cap: never written
+            if sp is not None:
+                sp.set(tokens=total_committed)
 
         m.total_tokens += total_committed
         if total_committed > 0 and draft_ok:
-            lpt = out.latency / total_committed
-            self.policy.observe(B, gamma, lpt,
-                                n_accepted=(total_committed - B) / max(B, 1)
-                                if gamma else None,
-                                delta_max=delta_max)
+            self._observe(hs, B, gamma, out.latency, total_committed,
+                          delta_max)
         if self.record_timeline:
             self._record_timeline(B, gamma, total_committed, out.latency,
                                   draft_ok)
@@ -780,15 +835,19 @@ class ServingEngine:
         m = self.metrics
         t_start = self.clock
 
+        tr = self._tracer()
+        hs = self.spans
         # 1. arrivals up to now; reap expired deadlines BEFORE dispatch so
         #    a dead-on-arrival request never consumes chunk budget
-        self._drain_arrivals()
-        reaped = self._reap_expired()
-
-        draft_ok = self.memmgr.can_speculate(self.clock) if self.memmgr else True
-        tr = self._tracer()
-
-        batch = self.scheduler.schedule_chunks()
+        with span(hs, "nightjar.admit") as sp:
+            self._drain_arrivals()
+            reaped = self._reap_expired()
+            draft_ok = (self.memmgr.can_speculate(self.clock) if self.memmgr
+                        else True)
+            batch = self.scheduler.schedule_chunks()
+            if sp is not None:
+                sp.set(admitted=len(batch.admitted),
+                       waiting=self.scheduler.num_waiting)
         if batch.empty:
             cands = [t for t in (self._next_income(), self._next_expiry())
                      if t is not None]
@@ -828,10 +887,11 @@ class ServingEngine:
 
         # 2. elastic memory triggers
         if self.memmgr is not None:
-            self.memmgr.step(
-                self.clock,
-                spec_disabled=(self.prev_gamma_effective == 0),
-                waiting=self.scheduler.num_waiting)
+            with span(hs, "nightjar.memmgr"):
+                self.memmgr.step(
+                    self.clock,
+                    spec_disabled=(self.prev_gamma_effective == 0),
+                    waiting=self.scheduler.num_waiting)
             draft_ok = self.memmgr.can_speculate(self.clock)
             if tr is not None:
                 self._trace_memmgr(tr)
@@ -843,7 +903,7 @@ class ServingEngine:
                 or self.spec_forced_off):
             gamma = 0
         else:
-            gamma = self.policy.select(B, delta_max=delta_max)
+            gamma = self._select(hs, B, delta_max)
 
         # 4. physical KV reservation for the decode rows (chunk rows were
         #    reserved block-by-block at schedule time), then switching cost
@@ -882,19 +942,19 @@ class ServingEngine:
                     tr.req_stage(s.req_id, self.clock, "decode",
                                  self.replica_id)
 
-        finished, clipped = self._commit_decode(decode, out.n_committed,
-                                                gamma)
-        total_committed -= clipped  # best-effort cap: tokens never written
+        with span(hs, "nightjar.commit") as sp:
+            finished, clipped = self._commit_decode(decode, out.n_committed,
+                                                    gamma)
+            total_committed -= clipped  # best-effort cap: never written
+            if sp is not None:
+                sp.set(tokens=total_committed)
 
         m.total_tokens += total_committed
         # the planner only learns from pure-decode steps: mixed-step latency
         # includes prefill work and would corrupt the latency-per-token signal
         if (total_committed > 0 and draft_ok and not batch.prefill_chunks):
-            lpt = out.latency / total_committed
-            self.policy.observe(B, gamma, lpt,
-                                n_accepted=(total_committed - B) / max(B, 1)
-                                if gamma else None,
-                                delta_max=delta_max)
+            self._observe(hs, B, gamma, out.latency, total_committed,
+                          delta_max)
         if self.record_timeline:
             self._record_timeline(B, gamma, total_committed, out.latency,
                                   draft_ok,

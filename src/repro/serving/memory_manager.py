@@ -25,18 +25,23 @@ are captured BEFORE migration reuses their below-boundary targets and
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
-from typing import Callable, List, Optional
+from typing import Callable, Dict, List, Optional
 
 from .kv_cache import BlockManager, MigrationPlan, PhysicalKVPool
+from .observability import span
 
 
 @dataclass
 class MemoryEvent:
     kind: str        # offload | expand | contract | reload
     at: float
-    latency: float
+    latency: float   # modelled (what the clock and the planner see)
     detail: dict = field(default_factory=dict)
+    # measured wall seconds of each physical hook the event ran
+    # (offload | grow | migrate | shrink | reload); empty without hooks
+    wall_s: Dict[str, float] = field(default_factory=dict)
 
 
 class ElasticMemoryManager:
@@ -79,6 +84,8 @@ class ElasticMemoryManager:
         # and contraction is suppressed until the stage clears
         self.force_offload = False
         self.events: List[MemoryEvent] = []
+        # wall-mode flight recorder (ServingEngine.attach_trace), or None
+        self.trace = None
 
     # ------------------------------------------------------------------
     @property
@@ -120,44 +127,68 @@ class ElasticMemoryManager:
             self._contract_and_reload(now)
 
     # ------------------------------------------------------------------
+    def _hook(self, kind: str, fn: Callable, wall: Dict[str, float],
+              *args, blocks: int):
+        """Run physical hook ``fn``, its wall seconds into ``wall[kind]``
+        (inside a ``nightjar.memmgr.<kind>`` span when traced)."""
+        tr = self.trace
+        t0 = time.perf_counter()
+        with span(tr, f"nightjar.memmgr.{kind}", blocks=blocks):
+            out = fn(*args)
+        wall[kind] = time.perf_counter() - t0
+        return out
+
     def _offload_and_expand(self, now: float) -> None:
+        wall: Dict[str, float] = {}
         if self.offload_fn is not None:
-            self.offload_fn()
+            self._hook("offload", self.offload_fn, wall,
+                       blocks=self.draft_blocks)
         self.draft_resident = False
         self._busy_until = now + self.offload_latency
-        self.events.append(MemoryEvent("offload", now, self.offload_latency))
+        self.events.append(MemoryEvent("offload", now, self.offload_latency,
+                                       wall_s=wall))
         start, end = self.bm.expand(self.draft_blocks)
+        wall = {}
         if self.grow_fn is not None:
-            self.grow_fn(self.draft_blocks)   # physical pages follow §6.3
+            # physical pages follow §6.3
+            self._hook("grow", self.grow_fn, wall, self.draft_blocks,
+                       blocks=self.draft_blocks)
         self.expanded = True
         self._low_mem_streak = 0
         self.events.append(MemoryEvent(
-            "expand", now, 0.0, {"range": (start, end)}))
+            "expand", now, 0.0, {"range": (start, end)}, wall))
 
     def _contract_and_reload(self, now: float) -> None:
         plan = self.bm.plan_contraction()
         if plan is None and self.bm.total_blocks != self.bm.base_blocks:
             return  # §6.4 step 2 verification failed — retry later
         migrate_latency = 0.0
+        wall: Dict[str, float] = {}
         if plan is not None:
             if self.flush_fn is not None:
                 self.flush_fn()   # capture contraction-time spills first
             if self.migrate_fn is not None:
-                migrate_latency = self.migrate_fn(plan) or 0.0
+                migrate_latency = self._hook("migrate", self.migrate_fn, wall,
+                                             plan, blocks=len(plan)) or 0.0
             self.bm.commit_contraction(plan)
-            self.events.append(MemoryEvent(
-                "contract", now, migrate_latency,
-                {"migrated_blocks": len(plan)}))
+            contract = MemoryEvent("contract", now, migrate_latency,
+                                   {"migrated_blocks": len(plan)}, wall)
         else:
             self.bm.total_blocks = self.bm.base_blocks
             self.bm.free = [b for b in self.bm.free if b < self.bm.boundary]
-            self.events.append(MemoryEvent("contract", now, 0.0,
-                                           {"migrated_blocks": 0}))
+            contract = MemoryEvent("contract", now, 0.0,
+                                   {"migrated_blocks": 0}, wall)
+        self.events.append(contract)
         if self.shrink_fn is not None:
-            self.shrink_fn(self.bm.base_blocks)  # physical pages follow §6.4
+            # physical pages follow §6.4
+            self._hook("shrink", self.shrink_fn, wall, self.bm.base_blocks,
+                       blocks=self.draft_blocks)
         self.expanded = False
+        wall = {}
         if self.reload_fn is not None:
-            self.reload_fn()
+            self._hook("reload", self.reload_fn, wall,
+                       blocks=self.draft_blocks)
         self.draft_resident = True
         self._busy_until = now + self.reload_latency + migrate_latency
-        self.events.append(MemoryEvent("reload", now, self.reload_latency))
+        self.events.append(MemoryEvent("reload", now, self.reload_latency,
+                                       wall_s=wall))

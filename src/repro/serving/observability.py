@@ -1,8 +1,13 @@
 """Flight recorder: deterministic structured tracing + metrics registry.
 
-Two cooperating pieces, both driven entirely by the shared *virtual* clock
-(no wall-clock reads anywhere — a trace is a pure function of config + seed,
-so two same-seed runs emit byte-identical trace files):
+Two cooperating pieces.  By default both are driven entirely by the shared
+*virtual* clock (no wall-clock reads anywhere — a trace is a pure function
+of config + seed, so two same-seed runs emit byte-identical trace files).
+In *wall* mode (``TraceRecorder(wall=True)``, the real tier) the recorder
+stamps its own monotonic clock instead, and its host spans
+(:meth:`TraceRecorder.span`, ``nightjar.*``) go to two sinks: the ring
+buffer and the JAX profiler's trace, as ``TraceAnnotation`` events on the
+profiler's clock:
 
 :class:`TraceRecorder`
     Structured spans/events for one serving run.  Per-request lifecycle is
@@ -27,9 +32,8 @@ so two same-seed runs emit byte-identical trace files):
     ring buffer (oldest evicted first, ``dropped`` counts evictions).
 
 :class:`MetricsRegistry`
-    Prometheus-flavoured counters / gauges / histograms with windowed
-    time-series snapshots (``snapshot``/``series``) and deterministic text
-    exposition (``exposition``) for the real tier's scrape endpoint.
+    Prometheus-flavoured counters / gauges / histograms with deterministic
+    text exposition (``exposition``) for the real tier's scrape endpoint.
 
 Exporters: ``export_jsonl`` (one sorted-key JSON object per line — the
 input format of ``benchmarks/trace_report.py``) and ``export_chrome``
@@ -39,7 +43,9 @@ thread lane, engine steps on lane 0).
 from __future__ import annotations
 
 import json
+import time
 from collections import deque
+from contextlib import nullcontext
 from typing import Dict, List, Optional, Tuple
 
 # request lifecycle stages (the waterfall axes) and terminal outcomes
@@ -119,14 +125,12 @@ class Histogram:
 
 
 class MetricsRegistry:
-    """Counters/gauges/histograms with windowed snapshots + Prometheus
-    text exposition.  Creation is memoized by name; re-registering a name
-    as a different type raises."""
+    """Counters/gauges/histograms + Prometheus text exposition.  Creation
+    is memoized by name; re-registering a name as a different type
+    raises."""
 
-    def __init__(self, *, series_capacity: int = 4096):
+    def __init__(self):
         self._metrics: Dict[str, object] = {}
-        # windowed time-series: one row per snapshot(t), ring-bounded
-        self.series: deque = deque(maxlen=series_capacity)
 
     def _get(self, name: str, cls, *args, **kw):
         m = self._metrics.get(name)
@@ -147,18 +151,6 @@ class MetricsRegistry:
     def histogram(self, name: str, help: str = "",
                   buckets: Tuple[float, ...] = DEFAULT_BUCKETS) -> Histogram:
         return self._get(name, Histogram, help, buckets)
-
-    def snapshot(self, t: float) -> dict:
-        """Capture every metric's current value as one time-series row."""
-        row: dict = {"t": _r(t)}
-        for name, m in self._metrics.items():
-            if isinstance(m, Histogram):
-                row[name] = {"count": m.count, "sum": _r(m.sum)}
-            else:
-                row[name] = _r(m.value) if isinstance(m.value, float) \
-                    else m.value
-        self.series.append(row)
-        return row
 
     def exposition(self) -> str:
         """Prometheus text format (deterministic: insertion order, repr
@@ -190,32 +182,120 @@ class MetricsRegistry:
 # ---------------------------------------------------------------------------
 
 
+_NULL = nullcontext()
+
+
+def span(rec: Optional["TraceRecorder"], name: str, **args):
+    """``rec.span(name, **args)``, or a context that does nothing when
+    ``rec`` is None (no wall-mode recorder attached): the one check each
+    instrumentation site pays."""
+    return _NULL if rec is None else rec.span(name, **args)
+
+
+class _Span:
+    """One open host span of a wall-mode recorder: a profiler annotation
+    while it is open, one ``ph: X`` ring event when it closes.  ``set``
+    adds arguments known only inside the span (both sinks get them)."""
+
+    __slots__ = ("rec", "name", "args", "ann", "t0")
+
+    def __init__(self, rec: "TraceRecorder", name: str, ann, args: dict):
+        self.rec, self.name, self.args, self.ann = rec, name, args, ann
+
+    def set(self, **args) -> None:
+        self.args.update(args)
+        self.ann.set_metadata(**args)
+
+    def __enter__(self) -> "_Span":
+        self.t0 = self.rec.now()
+        self.ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.ann.__exit__(*exc)
+        rec = self.rec
+        dur = rec.now() - self.t0
+        rec._emit({"ph": "X", "cat": "host", "name": self.name,
+                   "t": _r(self.t0), "dur": _r(dur), "pid": 0,
+                   "args": dict(sorted(self.args.items()))})
+        if self.name == "nightjar.device_wait":
+            rec.registry.counter("device_wait_seconds_total",
+                                 "wall seconds waiting on the device"
+                                 ).inc(dur)
+
+
 class TraceRecorder:
-    """Bounded, deterministic span/event recorder on the virtual clock.
+    """Bounded span/event recorder, on the virtual clock (deterministic)
+    or, with ``wall=True``, on its own monotonic clock.
 
     Every hook early-returns when ``enabled`` is False, and every
     instrumentation site guards on the recorder being attached at all —
     so a run without a recorder executes exactly the pre-recorder code
     path (the CI overhead gate pins this).
+
+    Wall mode is the real tier's: every hook stamps :meth:`now` in place
+    of the ``t`` the engine passes, and :meth:`span` / :meth:`engine_step`
+    open ``nightjar.*`` host spans that are also ``jax.profiler``
+    annotations (their arguments become the profiler event's stats).
+    :meth:`mark_clock` writes the zero-length ``nightjar.clock`` span whose
+    argument ``t`` is this clock's reading, so an export can be shifted
+    onto the profiler's timeline.
     """
 
     FLEET_PID = -1   # process lane for fleet-level (non-replica) events
 
     def __init__(self, *, capacity: int = EVENT_RING_CAP,
                  registry: Optional[MetricsRegistry] = None,
-                 snapshot_interval_s: float = 1.0,
-                 enabled: bool = True):
+                 enabled: bool = True, wall: bool = False):
         self.enabled = enabled
+        self.wall = wall
         self.events: deque = deque(maxlen=capacity)
         self.dropped = 0
         self.registry = registry if registry is not None else MetricsRegistry()
-        self.snapshot_interval_s = snapshot_interval_s
-        self._next_snapshot = snapshot_interval_s
         # req_id -> [stage, start_t, replica]: the open stage span
         self._open: Dict[int, list] = {}
         # req_id -> arrival (for the e2e histogram at finish)
         self._arrival: Dict[int, float] = {}
         self.outcomes: Dict[int, str] = {}
+        if wall:
+            from jax import profiler
+            self._annotation = profiler.TraceAnnotation
+            self._step_annotation = profiler.StepTraceAnnotation
+            self._origin = time.perf_counter()
+            self.steps = 0           # engine steps spanned since creation
+
+    # -- clock ------------------------------------------------------------
+    def now(self) -> float:
+        """Wall mode: seconds on the recorder's monotonic clock."""
+        return time.perf_counter() - self._origin
+
+    def _t(self, t: float) -> float:
+        """The stamp of an event the engine dates ``t``."""
+        return _r(self.now()) if self.wall else _r(t)
+
+    # -- wall-mode host spans ----------------------------------------------
+    def span(self, name: str, **args) -> _Span:
+        """A ``nightjar.*`` host span (wall mode only); use as a context
+        manager."""
+        return _Span(self, name, self._annotation(name, **args), args)
+
+    def engine_step(self) -> _Span:
+        """The ``nightjar.step`` span of one engine step: a profiler step
+        annotation carrying the step's number."""
+        self.steps += 1
+        return _Span(self, "nightjar.step",
+                     self._step_annotation("nightjar.step",
+                                           step_num=self.steps), {})
+
+    def mark_clock(self) -> None:
+        """The zero-length ``nightjar.clock`` span: its ``t`` is this
+        clock's reading as the profiler stamps the span's start."""
+        ann = self._annotation("nightjar.clock")
+        with ann:
+            t = _r(self.now())
+            ann.set_metadata(t=t)
+        self._emit({"ph": "X", "cat": "host", "name": "nightjar.clock",
+                    "t": t, "dur": 0.0, "pid": 0, "args": {"t": t}})
 
     # -- low-level emit -------------------------------------------------
     def _emit(self, ev: dict) -> None:
@@ -241,7 +321,7 @@ class TraceRecorder:
         if rid in self._open:
             self.req_stage(rid, t, "queue", replica)
             return
-        t = _r(t)
+        t = self._t(t)
         self._open[rid] = ["queue", t, replica]
         self._arrival[rid] = t
         self._emit({"ph": "i", "cat": "request", "name": "submit", "t": t,
@@ -261,7 +341,7 @@ class TraceRecorder:
         always contiguous and non-negative — the partition identity."""
         if not self.enabled:
             return
-        t = _r(t)
+        t = self._t(t)
         st = self._open.get(rid)
         if st is not None:
             prev_stage, t0, rep0 = st
@@ -280,7 +360,7 @@ class TraceRecorder:
         """Close the request's open span and stamp its terminal outcome."""
         if not self.enabled:
             return
-        t = _r(t)
+        t = self._t(t)
         st = self._open.pop(rid, None)
         rep = replica
         if st is not None:
@@ -311,17 +391,20 @@ class TraceRecorder:
                   prefill_tokens: int = 0, draft_ok: bool = True,
                   forced_off: bool = False) -> None:
         """One decode (or hybrid) step on the engine lane: the
-        (batch, gamma, n_accepted) tuple the planner observed."""
+        (batch, gamma, n_accepted) tuple the planner observed.  In wall
+        mode the engine's ``nightjar.step`` span is the step's event, and
+        this call only counts."""
         if not self.enabled:
             return
         t0, t1 = _r(t0), _r(t1)
-        self._emit({"ph": "X", "cat": "engine", "name": "step", "t": t0,
-                    "dur": _r(t1 - t0), "pid": replica,
-                    "args": {"B": batch, "gamma": gamma, "tokens": tokens,
-                             "accepted": accepted,
-                             "prefill_tokens": prefill_tokens,
-                             "draft_ok": draft_ok,
-                             "forced_off": forced_off}})
+        if not self.wall:
+            self._emit({"ph": "X", "cat": "engine", "name": "step",
+                        "t": t0, "dur": _r(t1 - t0), "pid": replica,
+                        "args": {"B": batch, "gamma": gamma,
+                                 "tokens": tokens, "accepted": accepted,
+                                 "prefill_tokens": prefill_tokens,
+                                 "draft_ok": draft_ok,
+                                 "forced_off": forced_off}})
         reg = self.registry
         reg.counter("steps_total", "engine steps executed").inc()
         reg.counter("tokens_committed_total", "committed tokens").inc(tokens)
@@ -335,10 +418,6 @@ class TraceRecorder:
         reg.gauge("gamma_selected", "speculative length chosen").set(gamma)
         reg.histogram("step_latency_seconds",
                       "engine step latency").observe(t1 - t0)
-        if t1 >= self._next_snapshot:
-            reg.snapshot(t1)
-            while self._next_snapshot <= t1:
-                self._next_snapshot += self.snapshot_interval_s
 
     # -- point events ----------------------------------------------------
     def instant(self, cat: str, name: str, t: float, *,
@@ -349,7 +428,7 @@ class TraceRecorder:
         restore, preempt, fault...)."""
         if not self.enabled:
             return
-        self._emit({"ph": "i", "cat": cat, "name": name, "t": _r(t),
+        self._emit({"ph": "i", "cat": cat, "name": name, "t": self._t(t),
                     "pid": self.FLEET_PID if replica is None else replica,
                     "args": {k: (_r(v) if isinstance(v, float) else v)
                              for k, v in sorted((args or {}).items())}})

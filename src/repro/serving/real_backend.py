@@ -42,12 +42,31 @@ from ..core.spec_decode import (make_ar_step, make_paged_ar_step,
 from ..models.registry import ModelAPI
 from .engine import StepOutcome
 from .kv_cache import BlockManager, OutOfBlocks
+from .observability import span
 from .paged_runtime import PagedKVRuntime, bucket_size, num_blocks_for
 from .request import Sequence
 
 
+
 def _bucket(n: int) -> int:
     return bucket_size(n)
+
+
+def _note_pages(tr, sp, tables: np.ndarray, ends, block_size: int) -> None:
+    """Sizes of one decode call, on its span and into the recorder's page
+    counters: the pages holding a key some real row attends (row ``i``
+    attends the positions below ``ends[i]``) and the pages the
+    paged-attention kernel walks (every page of the padded table, padded
+    rows included)."""
+    valid = sum(-(-int(e) // block_size) for e in ends)
+    walked = int(tables.size)
+    sp.set(rows=len(ends), padded_rows=tables.shape[0], width=tables.shape[1],
+           pages_valid=valid, pages_walked=walked)
+    reg = tr.registry
+    reg.counter("kv_pages_valid_total",
+                "KV pages holding a key a decode row attends").inc(valid)
+    reg.counter("kv_pages_walked_total",
+                "KV pages the decode kernel walks").inc(walked)
 
 
 def make_real_backend(target: ModelAPI, draft: ModelAPI, **kw):
@@ -113,6 +132,9 @@ class RealBackend:
 
         self.last_token: Dict[int, int] = {}
         self.tokens_out: Dict[int, List[int]] = {}
+        # wall-mode flight recorder (ServingEngine.attach_trace), or None:
+        # host spans inside each call (prepare / device_wait / readback)
+        self.trace = None
 
         # page donation keeps the pools in place on accelerators; CPU jax
         # cannot donate and would only warn
@@ -125,6 +147,7 @@ class RealBackend:
                                 temperature=temperature)
         self._ar_jit = jax.jit(ar, donate_argnums=(2,) if donate else ())
 
+        @jax.named_scope("extend")
         def _extend_target(key, params, pages, tokens, tables, start, valid):
             """Multi-token extension + next-token sample at each row's last
             valid position (batched prefill / chunked-prefill appends fused
@@ -138,6 +161,7 @@ class RealBackend:
                 nxt = jax.random.categorical(key, lg)
             return nxt, pages
 
+        @jax.named_scope("extend")
         def _extend_draft(params, pages, tokens, tables, start, valid):
             _, pages = draft.decode_step_paged(params, pages, tokens, tables,
                                                start, valid, last_only=True)
@@ -315,38 +339,53 @@ class RealBackend:
     def prefill(self, seqs: List[Sequence], *, with_draft: bool) -> float:
         """Batched prefill: every admitted prompt in ONE padded extension
         call (start=0), its KV scattered straight into the paged pool."""
+        tr = self.trace
         t0 = time.perf_counter()
-        rows = []
-        for s in seqs:
-            if self._owns_bm and s.req_id in self.bm.tables:
-                self.bm.release(s.req_id)  # recompute after preemption
-            self._ensure_alloc(s.req_id, s.request.prompt_len + 1)
-            toks = list(s.request.prompt_tokens)
-            rows.append((s, toks, 0, len(toks)))
-        self._apply_pending_copies()
-        tokens, start, valid, Bb = self._fill_rows(rows)
-        tables, _ = self.tkv.batch_tables(seqs, Bb)
-        nxt, self.tkv.pages = self._extend_t(
-            self._next_key(), self.tparams, self.tkv.pages, tokens, tables,
-            start, valid)
-        nxt = np.asarray(jax.block_until_ready(nxt))
-        do_draft = with_draft and self.draft_resident
-        if do_draft:
-            self.dkv.pages = self._extend_d(self.dparams, self.dkv.pages,
-                                            tokens, tables, start, valid)
-            jax.block_until_ready(self.dkv.pages)
-        for i, s in enumerate(seqs):
-            P = s.request.prompt_len
-            self.tkv.ctx[s.req_id] = P
-            self.tokens_out[s.req_id] = [int(nxt[i])]
-            self.last_token[s.req_id] = int(nxt[i])
-            s.generated = 0  # first token counted at the first decode commit
+        with span(tr, "nightjar.prefill") as sp:
+            with span(tr, "nightjar.prepare"):
+                rows = []
+                for s in seqs:
+                    if self._owns_bm and s.req_id in self.bm.tables:
+                        self.bm.release(s.req_id)  # recompute after preemption
+                    self._ensure_alloc(s.req_id, s.request.prompt_len + 1)
+                    toks = list(s.request.prompt_tokens)
+                    rows.append((s, toks, 0, len(toks)))
+                self._apply_pending_copies()
+                tokens, start, valid, Bb = self._fill_rows(rows)
+                tables, _ = self.tkv.batch_tables(seqs, Bb)
+            if sp is not None:
+                sp.set(rows=len(seqs), padded_rows=Bb, width=tables.shape[1])
+                tr.registry.counter(
+                    "prefill_tokens_total",
+                    "prompt tokens prefilled").inc(int(valid.sum()))
+            with span(tr, "nightjar.device_wait", program="extend_target"):
+                nxt, self.tkv.pages = self._extend_t(
+                    self._next_key(), self.tparams, self.tkv.pages, tokens,
+                    tables, start, valid)
+                jax.block_until_ready(nxt)
+            with span(tr, "nightjar.readback"):
+                nxt = np.asarray(nxt)
+            do_draft = with_draft and self.draft_resident
             if do_draft:
-                self.dkv.ctx[s.req_id] = P
-                s.delta = 0
-            else:
-                self.dkv.ctx[s.req_id] = 0
-                s.delta = P
+                with span(tr, "nightjar.device_wait", program="extend_draft"):
+                    self.dkv.pages = self._extend_d(
+                        self.dparams, self.dkv.pages, tokens, tables, start,
+                        valid)
+                    jax.block_until_ready(self.dkv.pages)
+            with span(tr, "nightjar.readback"):
+                for i, s in enumerate(seqs):
+                    P = s.request.prompt_len
+                    self.tkv.ctx[s.req_id] = P
+                    self.tokens_out[s.req_id] = [int(nxt[i])]
+                    self.last_token[s.req_id] = int(nxt[i])
+                    # first token counted at the first decode commit
+                    s.generated = 0
+                    if do_draft:
+                        self.dkv.ctx[s.req_id] = P
+                        s.delta = 0
+                    else:
+                        self.dkv.ctx[s.req_id] = 0
+                        s.delta = P
         return time.perf_counter() - t0
 
     def draft_catchup(self, seqs: List[Sequence]) -> float:
@@ -354,30 +393,38 @@ class RealBackend:
         (the physical C_switch cost) — one batched paged extension."""
         if not self.draft_resident:
             return 0.0
-        rows = []
-        for s in seqs:
-            ctx = self.tkv.ctx.get(s.req_id)
-            if ctx is None:
-                continue
-            dctx = self.dkv.ctx.get(s.req_id, 0)
-            if dctx > ctx:
-                dctx = 0  # stale (preempt-and-recompute): full re-prefill
-            if dctx >= ctx:
-                continue
-            stream = (list(s.request.prompt_tokens)
-                      + self.tokens_out.get(s.req_id, []))
-            rows.append((s, stream[dctx:ctx], dctx, ctx - dctx))
-        if not rows:
-            return 0.0
-        t0 = time.perf_counter()
-        tokens, start, valid, Bb = self._fill_rows(rows)
-        tables, _ = self.dkv.batch_tables([r[0] for r in rows], Bb)
-        self.dkv.pages = self._extend_d(self.dparams, self.dkv.pages, tokens,
-                                        tables, start, valid)
-        jax.block_until_ready(self.dkv.pages)
-        for s, _, _, _ in rows:
-            self.dkv.ctx[s.req_id] = self.tkv.ctx[s.req_id]
-            s.delta = 0
+        tr = self.trace
+        with span(tr, "nightjar.catchup") as sp:
+            with span(tr, "nightjar.prepare"):
+                rows = []
+                for s in seqs:
+                    ctx = self.tkv.ctx.get(s.req_id)
+                    if ctx is None:
+                        continue
+                    dctx = self.dkv.ctx.get(s.req_id, 0)
+                    if dctx > ctx:
+                        # stale (preempt-and-recompute): full re-prefill
+                        dctx = 0
+                    if dctx >= ctx:
+                        continue
+                    stream = (list(s.request.prompt_tokens)
+                              + self.tokens_out.get(s.req_id, []))
+                    rows.append((s, stream[dctx:ctx], dctx, ctx - dctx))
+                if not rows:
+                    return 0.0
+                t0 = time.perf_counter()
+                tokens, start, valid, Bb = self._fill_rows(rows)
+                tables, _ = self.dkv.batch_tables([r[0] for r in rows], Bb)
+            if sp is not None:
+                sp.set(rows=len(rows), padded_rows=Bb, width=tables.shape[1])
+            with span(tr, "nightjar.device_wait", program="extend_draft"):
+                self.dkv.pages = self._extend_d(self.dparams, self.dkv.pages,
+                                                tokens, tables, start, valid)
+                jax.block_until_ready(self.dkv.pages)
+            with span(tr, "nightjar.readback"):
+                for s, _, _, _ in rows:
+                    self.dkv.ctx[s.req_id] = self.tkv.ctx[s.req_id]
+                    s.delta = 0
         return time.perf_counter() - t0
 
     # ------------------------------------------------------------------
@@ -391,110 +438,139 @@ class RealBackend:
                 return StepOutcome(n_committed=[], latency=0.0)
             return self.step(decode, gamma)
         assert gamma == 0, "speculation is disabled while chunks are in flight"
-        if self.reserve(decode, 0):
-            raise OutOfBlocks("decode rows not reserved — engine must "
-                              "preempt before hybrid_step")
-        rows = []
-        for s, n in chunks:
-            c = s.prefilled  # authoritative (survives preempt-and-recompute)
-            self.tkv.ctx[s.req_id] = c
-            if c == 0:
-                self.dkv.ctx[s.req_id] = 0  # fresh / restarted sequence
-            self._ensure_alloc(s.req_id, c + n)
-            toks = list(s.request.prompt_tokens[c:c + n])
-            rows.append((s, toks, c, n))
-        n_chunks = len(rows)
-        for s in decode:
-            rows.append((s, [self.last_token[s.req_id]],
-                         self.tkv.ctx[s.req_id], 1))
+        tr = self.trace
+        with span(tr, "nightjar.decode") as sp:
+            with span(tr, "nightjar.prepare"):
+                if self.reserve(decode, 0):
+                    raise OutOfBlocks("decode rows not reserved — engine "
+                                      "must preempt before hybrid_step")
+                rows = []
+                for s, n in chunks:
+                    # authoritative (survives preempt-and-recompute)
+                    c = s.prefilled
+                    self.tkv.ctx[s.req_id] = c
+                    if c == 0:
+                        self.dkv.ctx[s.req_id] = 0  # fresh / restarted
+                    self._ensure_alloc(s.req_id, c + n)
+                    toks = list(s.request.prompt_tokens[c:c + n])
+                    rows.append((s, toks, c, n))
+                n_chunks = len(rows)
+                for s in decode:
+                    rows.append((s, [self.last_token[s.req_id]],
+                                 self.tkv.ctx[s.req_id], 1))
+                t0 = time.perf_counter()
+                # CoW forks queued at schedule/reserve time execute BEFORE
+                # the step's writes (their cost is real step latency)
+                self._apply_pending_copies()
+                tokens, start, valid, Bb = self._fill_rows(rows)
+                tables, _ = self.tkv.batch_tables([r[0] for r in rows], Bb)
+            if sp is not None:
+                _note_pages(tr, sp, tables, (start + valid)[:len(rows)],
+                            self.bm.block_size)
+            with span(tr, "nightjar.device_wait", program="extend_target"):
+                nxt, self.tkv.pages = self._extend_t(
+                    self._next_key(), self.tparams, self.tkv.pages, tokens,
+                    tables, start, valid)
+                jax.block_until_ready(nxt)
+            with span(tr, "nightjar.readback"):
+                nxt = np.asarray(nxt)
 
-        t0 = time.perf_counter()
-        # CoW forks queued at schedule/reserve time execute BEFORE the
-        # step's writes (their cost is real step latency)
-        self._apply_pending_copies()
-        tokens, start, valid, Bb = self._fill_rows(rows)
-        tables, _ = self.tkv.batch_tables([r[0] for r in rows], Bb)
-        nxt, self.tkv.pages = self._extend_t(
-            self._next_key(), self.tparams, self.tkv.pages, tokens, tables,
-            start, valid)
-        nxt = np.asarray(jax.block_until_ready(nxt))
+            do_draft = with_draft and self.draft_resident
+            if do_draft:
+                # the draft consumes the same chunk stream to keep its KV
+                # current (decode rows stay out: gamma=0 commits are charged
+                # to delta)
+                drows = [r for r in rows[:n_chunks]
+                         if self.dkv.ctx.get(r[0].req_id, 0) == r[2]]
+                if drows:
+                    with span(tr, "nightjar.prepare"):
+                        dtokens, dstart, dvalid, Db = self._fill_rows(drows)
+                        dtables, _ = self.dkv.batch_tables(
+                            [r[0] for r in drows], Db)
+                    with span(tr, "nightjar.device_wait",
+                              program="extend_draft"):
+                        self.dkv.pages = self._extend_d(
+                            self.dparams, self.dkv.pages, dtokens, dtables,
+                            dstart, dvalid)
+                        jax.block_until_ready(self.dkv.pages)
+                    for s, _, c, n in drows:
+                        self.dkv.ctx[s.req_id] = c + n
+            latency = time.perf_counter() - t0
 
-        do_draft = with_draft and self.draft_resident
-        if do_draft:
-            # the draft consumes the same chunk stream to keep its KV current
-            # (decode rows stay out: gamma=0 commits are charged to delta)
-            drows = [r for r in rows[:n_chunks]
-                     if self.dkv.ctx.get(r[0].req_id, 0) == r[2]]
-            if drows:
-                dtokens, dstart, dvalid, Db = self._fill_rows(drows)
-                dtables, _ = self.dkv.batch_tables([r[0] for r in drows], Db)
-                self.dkv.pages = self._extend_d(
-                    self.dparams, self.dkv.pages, dtokens, dtables, dstart,
-                    dvalid)
-                jax.block_until_ready(self.dkv.pages)
-                for s, _, c, n in drows:
-                    self.dkv.ctx[s.req_id] = c + n
-        latency = time.perf_counter() - t0
-
-        for i, (s, _, c, n) in enumerate(rows):
-            if i < n_chunks:
-                self.tkv.ctx[s.req_id] = c + n
-                if c + n == s.request.prompt_len:
-                    # final chunk: the sampled token is the first output x_N
-                    self.tokens_out[s.req_id] = [int(nxt[i])]
-                    self.last_token[s.req_id] = int(nxt[i])
-            else:
-                self.tokens_out[s.req_id].append(int(nxt[i]))
-                self.last_token[s.req_id] = int(nxt[i])
-                self.tkv.ctx[s.req_id] = c + 1
+            with span(tr, "nightjar.readback"):
+                for i, (s, _, c, n) in enumerate(rows):
+                    if i < n_chunks:
+                        self.tkv.ctx[s.req_id] = c + n
+                        if c + n == s.request.prompt_len:
+                            # final chunk: the sampled token is the first
+                            # output x_N
+                            self.tokens_out[s.req_id] = [int(nxt[i])]
+                            self.last_token[s.req_id] = int(nxt[i])
+                    else:
+                        self.tokens_out[s.req_id].append(int(nxt[i]))
+                        self.last_token[s.req_id] = int(nxt[i])
+                        self.tkv.ctx[s.req_id] = c + 1
         return StepOutcome(n_committed=[1] * len(decode), latency=latency)
 
     # ------------------------------------------------------------------
     def step(self, seqs: List[Sequence], gamma: int) -> StepOutcome:
-        if self.reserve(seqs, gamma):
-            raise OutOfBlocks("decode batch not reserved — engine must "
-                              "preempt before step")
-        self._apply_pending_copies()
-        n = len(seqs)
-        Bb = _bucket(n)
-        tables, lengths = self.tkv.batch_tables(seqs, Bb)
-        last = np.zeros((Bb,), np.int32)
-        for i, s in enumerate(seqs):
-            last[i] = self.last_token[s.req_id]
+        tr = self.trace
+        with span(tr, "nightjar.decode", gamma=gamma) as sp:
+            with span(tr, "nightjar.prepare"):
+                if self.reserve(seqs, gamma):
+                    raise OutOfBlocks("decode batch not reserved — engine "
+                                      "must preempt before step")
+                self._apply_pending_copies()
+                n = len(seqs)
+                Bb = _bucket(n)
+                tables, lengths = self.tkv.batch_tables(seqs, Bb)
+                last = np.zeros((Bb,), np.int32)
+                for i, s in enumerate(seqs):
+                    last[i] = self.last_token[s.req_id]
+            if sp is not None:
+                # the step writes, then attends, gamma + 1 positions per row
+                _note_pages(tr, sp, tables, lengths[:n] + gamma + 1,
+                            self.bm.block_size)
 
-        t0 = time.perf_counter()
-        if gamma == 0:
-            nxt, self.tkv.pages = self._ar_jit(
-                self._next_key(), self.tparams, self.tkv.pages, tables,
-                lengths, last)
-            nxt = np.asarray(jax.block_until_ready(nxt))
+            t0 = time.perf_counter()
+            if gamma == 0:
+                with span(tr, "nightjar.device_wait", program="decode"):
+                    nxt, self.tkv.pages = self._ar_jit(
+                        self._next_key(), self.tparams, self.tkv.pages,
+                        tables, lengths, last)
+                    jax.block_until_ready(nxt)
+                with span(tr, "nightjar.readback"):
+                    nxt = np.asarray(nxt)
+                    latency = time.perf_counter() - t0
+                    n_committed = []
+                    for i, s in enumerate(seqs):
+                        self.tokens_out[s.req_id].append(int(nxt[i]))
+                        self.last_token[s.req_id] = int(nxt[i])
+                        self.tkv.ctx[s.req_id] += 1
+                        n_committed.append(1)
+                return StepOutcome(n_committed=n_committed, latency=latency)
+
+            with span(tr, "nightjar.device_wait", program="spec"):
+                res = self._spec_jit(self._next_key(), self.tparams,
+                                     self.dparams, self.tkv.pages,
+                                     self.dkv.pages, tables, lengths, last,
+                                     gamma=gamma)
+                jax.block_until_ready(res.n_accepted)
             latency = time.perf_counter() - t0
-            n_committed = []
-            for i, s in enumerate(seqs):
-                self.tokens_out[s.req_id].append(int(nxt[i]))
-                self.last_token[s.req_id] = int(nxt[i])
-                self.tkv.ctx[s.req_id] += 1
-                n_committed.append(1)
-            return StepOutcome(n_committed=n_committed, latency=latency)
-
-        res = self._spec_jit(self._next_key(), self.tparams, self.dparams,
-                             self.tkv.pages, self.dkv.pages, tables, lengths,
-                             last, gamma=gamma)
-        jax.block_until_ready(res.n_accepted)
-        latency = time.perf_counter() - t0
-        self.tkv.pages, self.dkv.pages = res.tcache, res.dcache
-        toks = np.asarray(res.tokens)
-        n_acc = np.asarray(res.n_accepted)
-        last_np = np.asarray(res.last_token)
-        n_committed = []
-        for i, s in enumerate(seqs):
-            committed = [int(t) for t in toks[i] if t >= 0]
-            self.tokens_out[s.req_id].extend(committed)
-            self.last_token[s.req_id] = int(last_np[i])
-            n_keep = int(n_acc[i]) + 1
-            self.tkv.ctx[s.req_id] += n_keep
-            self.dkv.ctx[s.req_id] = self.tkv.ctx[s.req_id]
-            n_committed.append(n_keep)
+            with span(tr, "nightjar.readback"):
+                self.tkv.pages, self.dkv.pages = res.tcache, res.dcache
+                toks = np.asarray(res.tokens)
+                n_acc = np.asarray(res.n_accepted)
+                last_np = np.asarray(res.last_token)
+                n_committed = []
+                for i, s in enumerate(seqs):
+                    committed = [int(t) for t in toks[i] if t >= 0]
+                    self.tokens_out[s.req_id].extend(committed)
+                    self.last_token[s.req_id] = int(last_np[i])
+                    n_keep = int(n_acc[i]) + 1
+                    self.tkv.ctx[s.req_id] += n_keep
+                    self.dkv.ctx[s.req_id] = self.tkv.ctx[s.req_id]
+                    n_committed.append(n_keep)
         return StepOutcome(n_committed=n_committed, latency=latency)
 
     # ------------------------------------------------------------------

@@ -14,6 +14,7 @@ Pins the observability layer's three contracts:
 import json
 import os
 import sys
+import time
 
 import pytest
 
@@ -259,15 +260,41 @@ def test_registry_type_conflict_raises():
         reg.gauge("x")
 
 
-def test_registry_snapshot_series():
-    reg = MetricsRegistry(series_capacity=2)
-    c = reg.counter("n_total")
-    for t in (1.0, 2.0, 3.0):
-        c.inc()
-        reg.snapshot(t)
-    assert len(reg.series) == 2           # ring-bounded
-    assert reg.series[-1]["t"] == 3.0
-    assert reg.series[-1]["n_total"] == 3.0
+# ---------------------------------------------------------------------------
+# clocks: virtual (default) and wall
+# ---------------------------------------------------------------------------
+
+
+def test_wall_mode_stamps_the_recorders_monotonic_clock():
+    """The engine's ``t`` is ignored: stamps come from the recorder's own
+    clock, never decrease, and measure the wall time between hooks."""
+    rec = TraceRecorder(wall=True)
+    rec.req_submit(1, 1e6, 0)
+    time.sleep(0.01)
+    rec.req_stage(1, -5.0, "decode", 0)
+    rec.instant("engine", "mark", 42.0)
+    ts = [e["t"] for e in rec.events]
+    assert ts == sorted(ts) and 0.0 <= ts[0] < 1.0
+    queue = next(e for e in rec.events if e["name"] == "queue")
+    assert queue["dur"] >= 0.01
+    with rec.span("nightjar.admit", waiting=3) as sp:
+        sp.set(admitted=2)
+    ev = rec.events[-1]
+    assert (ev["name"], ev["args"]) == ("nightjar.admit",
+                                        {"admitted": 2, "waiting": 3})
+    assert ev["t"] >= ts[-1] and ev["dur"] >= 0.0
+
+
+def test_virtual_mode_stamps_the_engine_time_and_opens_no_host_spans():
+    rec = TraceRecorder()
+    rec.req_submit(1, 2.5, 0)
+    rec.req_stage(1, 4.0, "decode", 0)
+    rec.step_span(4.0, 4.5, 0, batch=2, gamma=1, tokens=3, accepted=1)
+    assert [e["t"] for e in rec.events] == [2.5, 2.5, 4.0]
+    assert rec.events[-1]["dur"] == 0.5
+    eng = build_sim_engine(_cfg(), "nightjar", trace=rec)
+    assert eng.trace is rec and eng.spans is None
+    assert not any(e["name"].startswith("nightjar.") for e in rec.events)
 
 
 # ---------------------------------------------------------------------------

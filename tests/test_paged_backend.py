@@ -326,6 +326,39 @@ def test_memmgr_drives_physical_pool_hooks():
     bm.check_invariants()
 
 
+def test_memmgr_events_carry_measured_hook_seconds():
+    """Each event records the wall seconds of the hooks it ran; the
+    modelled latency and the transfer horizon stay as configured."""
+    import time
+    from repro.serving.memory_manager import ElasticMemoryManager
+
+    def nap(*_):
+        time.sleep(0.005)
+
+    bm = BlockManager(8, 4)
+    mm = ElasticMemoryManager(
+        bm, draft_blocks=4, t_persist=1, tau_low_frac=0.5,
+        offload_latency=0.25, reload_latency=0.5, offload_fn=nap,
+        reload_fn=nap, migrate_fn=lambda plan: nap() or 0.0, grow_fn=nap,
+        shrink_fn=nap)
+    bm.allocate(1, 8 * 4)
+    mm.step(0.0, spec_disabled=True, waiting=4)
+    assert not mm.can_speculate(0.2) and mm._busy_until == 0.25
+    bm.release(1)
+    mm.step(1.0, spec_disabled=True, waiting=0)
+    ev = {e.kind: e for e in mm.events}
+    assert [e.kind for e in mm.events] == ["offload", "expand", "contract",
+                                           "reload"]
+    assert set(ev["offload"].wall_s) == {"offload"}
+    assert set(ev["expand"].wall_s) == {"grow"}
+    assert set(ev["contract"].wall_s) <= {"migrate", "shrink"}
+    assert "shrink" in ev["contract"].wall_s
+    assert set(ev["reload"].wall_s) == {"reload"}
+    assert all(s >= 0.005 for e in mm.events for s in e.wall_s.values())
+    assert (ev["offload"].latency, ev["reload"].latency) == (0.25, 0.5)
+    assert mm._busy_until == 1.5
+
+
 # ---------------------------------------------------------------------------
 # tier-1: adaptive chunk budget (roofline knee)
 # ---------------------------------------------------------------------------
