@@ -1080,19 +1080,19 @@ def kernel_microbench(csv: CSV, fast: bool):
 
     B, H, KH, D, bs, maxb = 8, 16, 4, 128, 16, 16
     q = jax.random.normal(key, (B, H, D))
-    kp = jax.random.normal(key, (256, bs, KH, D))
-    vp = jax.random.normal(key, (256, bs, KH, D))
+    kp = jax.random.normal(key, (1, 256, bs, KH * D))   # one-layer pool
+    vp = jax.random.normal(key, (1, 256, bs, KH * D))
     tables = jax.random.randint(key, (B, maxb), 0, 256)
     lengths = jnp.full((B,), maxb * bs)
     _, dt = timed(lambda: ops.paged_attention(
-        q, kp, vp, tables, lengths).block_until_ready(), repeat=5)
+        q, kp, vp, 0, tables, lengths).block_until_ready(), repeat=5)
     csv.add("kernel.paged_attention.b8h16", dt * 1e6,
             f"ctx={maxb*bs}")
 
     # multi-query extension (speculative verify / chunked-prefill appends)
     qm = jax.random.normal(key, (B, 4, H, D))
     _, dt = timed(lambda: ops.paged_attention(
-        qm, kp, vp, tables, lengths).block_until_ready(), repeat=5)
+        qm, kp, vp, 0, tables, lengths).block_until_ready(), repeat=5)
     csv.add("kernel.paged_attention.b8t4h16", dt * 1e6,
             f"ctx={maxb*bs};T=4")
 

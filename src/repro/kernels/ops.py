@@ -47,15 +47,23 @@ def interpret_kernels():
         _INTERPRET.reset(token)
 
 
-def paged_attention(q, k_pages, v_pages, block_tables, lengths):
-    """q may be (B, H, D) single-query decode or (B, T, H, D) multi-query
-    (speculative verify / prefill extensions); see ref for masking."""
+def paged_attention(q, k_pool, v_pool, layer, block_tables, lengths):
+    """Attention over layer ``layer`` of the stacked paged pools (L, NB+1,
+    block_size, KH * D).  q may be (B, H, D) single-query decode or (B, T,
+    H, D) multi-query (speculative verify / prefill extensions); see ref
+    for masking.  The kernel reads the pools in place; the oracle slices
+    the layer."""
     mode = kernel_mode()
     if mode == "oracle":
-        return ref.paged_attention_ref(q, k_pages, v_pages, block_tables,
-                                       lengths)
-    return paged_mod.paged_attention(q, k_pages, v_pages, block_tables,
-                                     lengths, interpret=mode == "interpret")
+        D = q.shape[-1]
+        _, nb, bs, lanes = k_pool.shape
+        heads = lambda p: jax.lax.dynamic_index_in_dim(
+            p, layer, keepdims=False).reshape(nb, bs, lanes // D, D)
+        return ref.paged_attention_ref(q, heads(k_pool), heads(v_pool),
+                                       block_tables, lengths)
+    return paged_mod.paged_attention_pool(
+        q, k_pool, v_pool, layer, block_tables, lengths,
+        interpret=mode == "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=("mode",), donate_argnums=(0,))

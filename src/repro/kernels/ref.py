@@ -2,6 +2,13 @@
 
 These are the ground truth the kernels are validated against
 (``tests/test_kernels.py`` sweeps shapes/dtypes with assert_allclose).
+
+The paged-attention oracle takes one layer's pages ``(num_blocks,
+block_size, KH, D)`` (``ops`` slices it out of the stacked pool), gathers
+every page of the padded table and masks the positions past each query's
+own: the same answer as the kernel, which reads the pool in place and only
+the pages up to each row's last attended key.  Slots it masks must hold
+finite values.
 """
 from __future__ import annotations
 

@@ -297,18 +297,14 @@ def apply_layer_decode_paged(cfg, layer, h, pages, i, tables, positions,
     (B, T, d); pages {"k_pages", "v_pages"} (L, NB+1, bs, KH * hd).  New
     K/V are scattered through the block tables first, then every query
     attends to all paged slots at or before its position (the multi-query
-    paged-attention kernel, or its jnp oracle off the TPU — ``kernels.ops``
-    decides)."""
+    paged-attention kernel, which reads layer ``i`` of the whole pools in
+    place, or its jnp oracle off the TPU — ``kernels.ops`` decides)."""
     from ..kernels import ops
     x = apply_norm(cfg, h, layer["ln1"])
     q, k, v = qkv_project(cfg, layer["attn"], x, positions)
     kp = _paged_write(pages["k_pages"], i, k, tables, positions, valid)
     vp = _paged_write(pages["v_pages"], i, v, tables, positions, valid)
-    heads = kp.shape[1:3] + k.shape[2:]                 # (NB+1, bs, KH, hd)
-    o = ops.paged_attention(
-        q, jax.lax.dynamic_index_in_dim(kp, i, keepdims=False).reshape(heads),
-        jax.lax.dynamic_index_in_dim(vp, i, keepdims=False).reshape(heads),
-        tables, lengths)
+    o = ops.paged_attention(q, kp, vp, i, tables, lengths)
     h = h + attn_output(layer["attn"], o.astype(h.dtype))
     x = apply_norm(cfg, h, layer["ln2"])
     if cfg.moe_num_experts:

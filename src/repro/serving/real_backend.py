@@ -3,7 +3,7 @@
 Two implementations share the Backend protocol:
 
 * :class:`RealBackend` — the paged-KV runtime (production path for
-  attention-family models).  ``(L, num_blocks, block_size, KH, hd)``
+  attention-family models).  ``(L, num_blocks + 1, block_size, KH * hd)``
   key/value pools per model are allocated ONCE and driven by the
   :class:`BlockManager` block tables: admission, decode, speculative
   verification, chunked prefill, eviction and completion touch only int32
@@ -39,6 +39,7 @@ import numpy as np
 
 from ..core.spec_decode import (make_ar_step, make_paged_ar_step,
                                 make_paged_spec_step, make_spec_step)
+from ..kernels import paged_attention as paged_mod
 from ..models.registry import ModelAPI
 from .engine import StepOutcome
 from .kv_cache import BlockManager, OutOfBlocks
@@ -52,21 +53,22 @@ def _bucket(n: int) -> int:
     return bucket_size(n)
 
 
-def _note_pages(tr, sp, tables: np.ndarray, ends, block_size: int) -> None:
+def _note_pages(tr, sp, tables: np.ndarray, ends, read: int,
+                block_size: int) -> None:
     """Sizes of one decode call, on its span and into the recorder's page
     counters: the pages holding a key some real row attends (row ``i``
-    attends the positions below ``ends[i]``) and the pages the
-    paged-attention kernel walks (every page of the padded table, padded
-    rows included)."""
+    attends the positions below ``ends[i]``), the pages the target's
+    paged-attention kernel copies (``read``: each row's pages up to its
+    last attended key, padded rows included) and the pages of the padded
+    table."""
     valid = sum(-(-int(e) // block_size) for e in ends)
-    walked = int(tables.size)
     sp.set(rows=len(ends), padded_rows=tables.shape[0], width=tables.shape[1],
-           pages_valid=valid, pages_walked=walked)
+           pages_valid=valid, pages_walked=read, table_pages=int(tables.size))
     reg = tr.registry
     reg.counter("kv_pages_valid_total",
                 "KV pages holding a key a decode row attends").inc(valid)
     reg.counter("kv_pages_walked_total",
-                "KV pages the decode kernel walks").inc(walked)
+                "KV pages the decode kernel reads").inc(read)
 
 
 def make_real_backend(target: ModelAPI, draft: ModelAPI, **kw):
@@ -173,6 +175,15 @@ class RealBackend:
                                  donate_argnums=(1,) if donate else ())
 
     # ------------------------------------------------------------------
+    def _pages_read(self, tables, lengths, n_queries: int) -> int:
+        """Pages the target's paged-attention kernel copies in one layer
+        of a call extending every row by ``n_queries`` to ``lengths``."""
+        c = self.target.cfg
+        return paged_mod.pages_read(lengths, n_queries, c.num_heads,
+                                    c.resolved_head_dim,
+                                    self.tkv.pages["k_pages"],
+                                    tables.shape[1])
+
     def _next_key(self):
         self.key, k = jax.random.split(self.key)
         return k
@@ -466,6 +477,8 @@ class RealBackend:
                 tables, _ = self.tkv.batch_tables([r[0] for r in rows], Bb)
             if sp is not None:
                 _note_pages(tr, sp, tables, (start + valid)[:len(rows)],
+                            self._pages_read(tables, start + tokens.shape[1],
+                                             tokens.shape[1]),
                             self.bm.block_size)
             with span(tr, "nightjar.device_wait", program="extend_target"):
                 nxt, self.tkv.pages = self._extend_t(
@@ -530,6 +543,8 @@ class RealBackend:
             if sp is not None:
                 # the step writes, then attends, gamma + 1 positions per row
                 _note_pages(tr, sp, tables, lengths[:n] + gamma + 1,
+                            self._pages_read(tables, lengths + gamma + 1,
+                                             gamma + 1),
                             self.bm.block_size)
 
             t0 = time.perf_counter()

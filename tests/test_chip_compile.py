@@ -6,16 +6,19 @@ that is described and not attached, and refuses what the chip would refuse
 import, so every test worker collects the same tests and only the worker
 given this file loads the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs
-from repro.core.spec_decode import make_paged_spec_step
+from repro.core.spec_decode import make_paged_ar_step, make_paged_spec_step
 from repro.kernels import ops
 from repro.kernels.block_migration import migrate_blocks
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import (paged_attention,
+                                           paged_attention_pool)
 from repro.models import registry
 from repro.serving.costmodel import RooflineCostModel, TPU_V5E
 from repro.serving.paged_runtime import num_blocks_for
@@ -24,6 +27,8 @@ from repro.serving.real_backend import BLOCK_SIZE as BLOCK
 ARCH = "paper-7b-1chip"
 GAMMA_MAX = 5
 B, MAX_PROMPT = 8, 2048
+# the benchmark cell's decode and verify calls: 64 rows, 128-page tables
+CELL_B, CELL_WIDTH = 64, 128
 # the pool build_real_engine sizes for the pair on one chip
 NUM_BLOCKS = num_blocks_for(RooflineCostModel(TPU_V5E),
                             configs.get_config(ARCH),
@@ -75,6 +80,24 @@ def test_paged_attention_compiles(one_chip, draft, T):
         s((B, T, H, D), jnp.bfloat16), pages, pages,
         s((B, MAX_PROMPT // BLOCK), jnp.int32), s((B,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("T", [1, GAMMA_MAX + 1])
+@pytest.mark.parametrize("draft", [False, True], ids=["target", "draft"])
+def test_paged_attention_compiles_at_cell_shapes(one_chip, draft, T):
+    """The kernel as the serving steps call it: the whole stacked pool of
+    every layer, a runtime layer index, the cell's batch and tables."""
+    H, KH, D, L = _widths(draft)
+    s = lambda *a: _sds(one_chip, *a)
+    pool = s((L, NUM_BLOCKS + 1, BLOCK, KH * D), jnp.bfloat16)
+    compiled = jax.jit(paged_attention_pool).lower(
+        s((CELL_B, T, H, D), jnp.bfloat16), pool, pool, s((), jnp.int32),
+        s((CELL_B, CELL_WIDTH), jnp.int32),
+        s((CELL_B,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # nothing pool-sized besides the pools themselves
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        NUM_BLOCKS * BLOCK * KH * D * 2 // 100)
 
 
 @pytest.mark.parametrize("draft", [False, True], ids=["target", "draft"])
@@ -131,6 +154,54 @@ def test_paged_spec_step_compiles_with_kernel(one_chip, pair, monkeypatch):
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes == pools
     assert ma.temp_size_in_bytes < pools // 2
+
+
+def _slab_sized_ops(compiled, *pools):
+    """Instructions of the compiled program whose result holds exactly one
+    layer's slab of a pool, (NB+1, block_size, KH * hd) elements: a slice,
+    layout change or transpose of the pool that the kernel would read
+    instead of the pool itself."""
+    slabs = {x.size // x.shape[0] for p in pools for x in jax.tree.leaves(p)}
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]", line)
+        if m is None:
+            continue
+        n = 1
+        for d in m.group(1).split(","):
+            n *= int(d) if d else 1
+        if n in slabs:
+            found.append(line.strip()[:120])
+    return found
+
+
+@pytest.mark.parametrize("step", ["ar", "spec"])
+def test_paged_steps_read_the_pools_in_place(one_chip, pair, monkeypatch,
+                                             step):
+    """At the cell's batch and table width, the compiled decode (gamma 0)
+    and speculative steps hand the kernel the stacked pools: no op writes
+    a buffer of one layer's pool slab (a slice, layout copy or transpose
+    of the pool), and the temporaries stay far below one slab."""
+    monkeypatch.setattr(ops, "kernel_mode", lambda: "compiled")
+    t, d, key, sh = pair
+    s = lambda *a: _sds(one_chip, *a)
+    tables = s((CELL_B, CELL_WIDTH), jnp.int32)
+    rows = s((CELL_B,), jnp.int32)
+    if step == "ar":
+        fn = jax.jit(make_paged_ar_step(t), donate_argnums=(2,))
+        compiled = fn.lower(key, sh["tparams"], sh["tpages"], tables, rows,
+                            rows).compile()
+    else:
+        fn = jax.jit(make_paged_spec_step(t, d), static_argnames=("gamma",),
+                     donate_argnums=(3, 4))
+        compiled = fn.lower(key, sh["tparams"], sh["dparams"], sh["tpages"],
+                            sh["dpages"], tables, rows, rows,
+                            gamma=GAMMA_MAX).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _slab_sized_ops(compiled, sh["tpages"], sh["dpages"]) == []
+    slab = min(x.size // x.shape[0] * x.dtype.itemsize
+               for x in jax.tree.leaves(sh["dpages"]))
+    assert compiled.memory_analysis().temp_size_in_bytes < slab // 4
 
 
 def test_prefill_extension_fits(one_chip, pair, monkeypatch):

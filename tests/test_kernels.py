@@ -7,7 +7,9 @@ import pytest
 from repro.kernels import ops, ref
 from repro.kernels.block_migration import migrate_blocks
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import (paged_attention,
+                                           paged_attention_pool, pages_read,
+                                           plan)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -54,7 +56,7 @@ def test_paged_attention_sweep(B, H, KH, D, bs, maxb, dtype):
                                np.asarray(want, np.float32), atol=atol)
 
 
-# T * G > MAX_ROWS spans several query-row tiles (the last one padded)
+# T=160 at G=8 spans two query-row tiles (the last one padded; see plan)
 @pytest.mark.parametrize("T", [1, 4, 8, 160])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("B,H,KH,D,bs,maxb", [
@@ -101,6 +103,118 @@ def test_paged_attention_multiquery_is_causal_within_extension():
                                          lengths - T + t + 1)
         np.testing.assert_allclose(np.asarray(multi[:, t]),
                                    np.asarray(single), atol=2e-5)
+
+
+# the paper pair's attention: target 28/4 heads of 128, draft 14/2 of 64
+PAPER_HEADS = {"target": (28, 4, 128), "draft": (14, 2, 64)}
+BS, WIDTH = 16, 40
+
+
+def _one_tile_plus_one(H, KH, D):
+    """The least T whose T * G query rows span two row tiles."""
+    _, rows = plan(1 << 20, WIDTH, BS, KH, D, 2)
+    return rows // (H // KH) + 1
+
+
+def _ragged_lengths(T, chunk):
+    """Valid keys per row, T new ones included: one key, exact page
+    boundaries, a page past a whole chunk, the full table, and the rest
+    ragged far below it."""
+    keys = [1, BS, 3 * BS, chunk * BS + 1, WIDTH * BS, 17, 250, 5 * BS - 1]
+    return np.asarray([min(max(k, T), WIDTH * BS) for k in keys], np.int32)
+
+
+def _paper_case(which, T, L=1, seed=0):
+    """q, stacked bf16 pools (L, NB+1, BS, KH * D), disjoint block tables
+    (the trash block last) and ragged lengths at the paper pair's widths."""
+    H, KH, D = PAPER_HEADS[which]
+    chunk, _ = plan(T * (H // KH), WIDTH, BS, KH, D, 2)
+    lengths = _ragged_lengths(T, chunk)
+    B = len(lengths)
+    nb = B * WIDTH + 1
+    rng = np.random.default_rng(seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (B, T, H, D)).astype(jnp.bfloat16)
+    kpool = jax.random.normal(ks[1], (L, nb, BS, KH * D)).astype(jnp.bfloat16)
+    vpool = jax.random.normal(ks[2], (L, nb, BS, KH * D)).astype(jnp.bfloat16)
+    tables = rng.permutation(nb - 1).reshape(B, WIDTH).astype(np.int32)
+    return q, kpool, vpool, tables, lengths
+
+
+def _oracle(q, kpool, vpool, layer, tables, lengths):
+    D = q.shape[-1]
+    _, nb, bs, lanes = kpool.shape
+    heads = lambda p: p[layer].reshape(nb, bs, lanes // D, D)
+    return ref.paged_attention_ref(q, heads(kpool), heads(vpool),
+                                   jnp.asarray(tables), jnp.asarray(lengths))
+
+
+def _close(got, want):
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2)
+
+
+@pytest.mark.parametrize("which", ["target", "draft"])
+@pytest.mark.parametrize("T", [1, 6, "two_tiles"])
+def test_paged_attention_paper_widths_ragged(which, T):
+    """G=7 at the pair's head widths, rows from one key to the full table
+    (page counts on and off the kernel's chunk), T=1 decode, T=6 verify
+    and an extension one query past a single row tile."""
+    if T == "two_tiles":
+        T = _one_tile_plus_one(*PAPER_HEADS[which])
+    q, kpool, vpool, tables, lengths = _paper_case(which, T)
+    got = paged_attention_pool(q, kpool, vpool, 0, tables, lengths,
+                               interpret=True)
+    _close(got, _oracle(q, kpool, vpool, 0, tables, lengths))
+
+
+@pytest.mark.parametrize("layer", [1, 2])
+@pytest.mark.parametrize("which", ["target", "draft"])
+def test_paged_attention_reads_its_layer_of_the_stacked_pool(which, layer):
+    q, kpool, vpool, tables, lengths = _paper_case(which, 6, L=3, seed=layer)
+    got = paged_attention_pool(q, kpool, vpool, jnp.int32(layer), tables,
+                               lengths, interpret=True)
+    _close(got, _oracle(q, kpool, vpool, layer, tables, lengths))
+    other = _oracle(q, kpool, vpool, layer - 1, tables, lengths)
+    assert not np.allclose(np.asarray(got, np.float32),
+                           np.asarray(other, np.float32), atol=2e-2)
+
+
+@pytest.mark.parametrize("which", ["target", "draft"])
+@pytest.mark.parametrize("T", [1, 6])
+def test_paged_attention_never_reads_past_a_row(which, T):
+    """Every page past each row's last key in its table, and every block no
+    table names, holds NaN: the kernel's output equals the oracle's on the
+    clean pools, so it never read them."""
+    q, kpool, vpool, tables, lengths = _paper_case(which, T, L=2, seed=3)
+    want = _oracle(q, kpool, vpool, 1, tables, lengths)
+    read = np.zeros(kpool.shape[1], bool)
+    for row, n in zip(tables, lengths):
+        read[row[:-(-int(n) // BS)]] = True
+    mask = jnp.asarray(~read)[None, :, None, None]
+    nan = jnp.asarray(np.nan, kpool.dtype)
+    got = paged_attention_pool(q, jnp.where(mask, nan, kpool),
+                               jnp.where(mask, nan, vpool), 1, tables,
+                               lengths, interpret=True)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    _close(got, want)
+
+
+def test_pages_read_counts_each_rows_valid_pages():
+    """Decode and verify copy each row's pages up to its last key, padded
+    rows (length T) included; an extension over two row tiles copies the
+    first tile's pages up to its own last query, then the row's."""
+    pool = jax.ShapeDtypeStruct((2, 9, BS, 4 * 128), jnp.bfloat16)
+    lengths = np.asarray([1, BS, BS + 1, WIDTH * BS, 1])
+    assert pages_read(lengths, 1, 28, 128, pool, WIDTH) == 1 + 1 + 2 + 40 + 1
+    assert pages_read(lengths + 5, 6, 28, 128, pool, WIDTH) == (
+        1 + 2 + 2 + 40 + 1)
+    T = _one_tile_plus_one(28, 4, 128)
+    _, rows = plan(T * 7, WIDTH, BS, 4, 128, 2)
+    first = (rows - 1) // 7 + 1           # keys the first tile attends
+    n = 300 + T
+    assert pages_read(np.asarray([n]), T, 28, 128, pool, WIDTH) == (
+        -(-(n - T + first) // BS) + -(-n // BS))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
