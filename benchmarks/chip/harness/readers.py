@@ -83,14 +83,15 @@ def target_flops_in_window(ctx: Context) -> float:
 def attention_roofline_seconds(ctx: Context) -> float:
     """Least time the paged-attention kernel calls of the window needed:
     one call per layer of each model in each backend call, with the rows
-    each call serves (valid tokens only)."""
+    each call serves (valid tokens only), each kind of layer counted by
+    its own keys."""
     t, d, pk = ctx.target, ctx.draft, ctx.peaks
     secs = 0.0
 
-    def add(spec, rows, times=1):
+    def add(spec, rows):
         nonlocal secs
-        f, b = counts.attention_call(spec, rows)
-        secs += times * spec["layers"] * counts.roofline_seconds(f, b, pk)
+        for layers, f, b in counts.attention_calls(spec, rows):
+            secs += layers * counts.roofline_seconds(f, b, pk)
 
     for at, kind, rec in ctx.window.backend_calls:
         if not ctx.in_window(at):

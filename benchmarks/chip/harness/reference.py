@@ -1,16 +1,12 @@
-"""The plain reference: a dense decoder (Qwen2 / Qwen3 layout) written in
-straightforward ``jax.numpy``, float32 at "highest" matmul precision, with
-no kernels, cache or batching.  It imports nothing of the program and reads
-only the benchmark's own weights, laid out as ``weights/random.py`` draws
-them.
+"""The plain reference, in straightforward ``jax.numpy``, float32 at
+"highest" matmul precision, with no kernels, cache or batching.  It imports
+nothing of the program and reads only the benchmark's own weights, laid out
+as ``weights/random.py`` draws them.
 
-Equations (per layer, pre-norm residual):
-  x  = rmsnorm(h) * ln1;  q, k, v = x Wq (+bq), x Wk (+bk), x Wv (+bv)
-  q, k = rmsnorm_per_head(q) * q_norm, ... (Qwen3 only), then rotary
-  embedding (half-split rotation, theta from the config) at positions 0..S-1
-  o  = softmax(q k^T / sqrt(hd), causal) v  with grouped KV heads;  h += o Wo
-  x  = rmsnorm(h) * ln2;  h += (silu(x Wg) * (x Wu)) Wd
-  logits = rmsnorm(h) * final_norm  @ (lm_head or embed)^T
+A model's equations are its architecture module's (``archs/<name>.py``,
+``hidden`` and ``logits``), written from the primitives here that every
+layout shares: the matmul, RMSNorm, rotary embedding and causal attention.
+This file holds the comparison over served tokens.
 
 ``quant=True`` computes every matmul in simulated int8 (weights per output
 channel, activations per row, symmetric, dequantized to float32): the
@@ -18,9 +14,8 @@ control, one precision below the configuration's bfloat16.
 
 Sequences are computed one at a time, padded at the end to a multiple of
 ``PAD`` tokens (causal attention makes the padding invisible to earlier
-positions); queries go in blocks of ``Q_BLOCK`` and the SwiGLU in
-``FF_CHUNKS`` column chunks, so the working set stays near 1 GB at the
-served widths."""
+positions); queries go in blocks of ``Q_BLOCK``, so the working set stays
+near 1 GB at the served widths."""
 from __future__ import annotations
 
 import functools
@@ -29,9 +24,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .models import arch
+
 PAD = 1024
 Q_BLOCK = 512
-FF_CHUNKS = 4
 V_BLOCK = 256          # logit rows computed at a time
 HI = jax.lax.Precision.HIGHEST
 
@@ -43,7 +39,7 @@ def _fq(x, axis):
     return jnp.clip(jnp.round(x / s), -127, 127) * s
 
 
-def _mm(x, w, quant: bool):
+def mm(x, w, quant: bool):
     """x (..., K) @ w (K, N) in float32; w arrives in bf16."""
     w = w.astype(jnp.float32)
     if quant:
@@ -52,12 +48,12 @@ def _mm(x, w, quant: bool):
     return jnp.matmul(x, w, precision=HI)
 
 
-def _rms(x, w, eps):
+def rms(x, w, eps):
     x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
     return x * w.astype(jnp.float32)
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """x: (S, heads, hd) at positions 0..S-1."""
     S, _, hd = x.shape
     freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
@@ -67,23 +63,11 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _layer(s, quant, h, lw):
-    S, d = h.shape
-    H, KH, hd, eps = s["heads"], s["kv_heads"], s["head_dim"], s["norm_eps"]
-    a = lw["attn"]
-    x = _rms(h, lw["ln1"]["scale"], eps)
-    q = _mm(x, a["wq"].reshape(d, H * hd), quant).reshape(S, H, hd)
-    k = _mm(x, a["wk"].reshape(d, KH * hd), quant).reshape(S, KH, hd)
-    v = _mm(x, a["wv"].reshape(d, KH * hd), quant).reshape(S, KH, hd)
-    if "bq" in a:
-        q = q + a["bq"].astype(jnp.float32)
-        k = k + a["bk"].astype(jnp.float32)
-        v = v + a["bv"].astype(jnp.float32)
-    if "q_norm" in a:
-        q = _rms(q, a["q_norm"], eps)
-        k = _rms(k, a["k_norm"], eps)
-    q, k = _rope(q, s["rope_theta"]), _rope(k, s["rope_theta"])
-    G = H // KH
+def attention(q, k, v):
+    """Causal softmax attention of q (S, H, hd) over k, v (S, KH, hd) with
+    H / KH query heads to each KV head, scaled by 1 / sqrt(hd); (S, H, hd)."""
+    S, H, hd = q.shape
+    G = H // k.shape[1]
     kg = jnp.repeat(k, G, axis=1)                       # (S, H, hd)
     vg = jnp.repeat(v, G, axis=1)
     outs = []
@@ -95,36 +79,19 @@ def _layer(s, quant, h, lw):
         sc = jnp.where(mask[None], sc, -jnp.inf)
         p = jax.nn.softmax(sc, axis=-1)
         outs.append(jnp.einsum("hqk,khd->qhd", p, vg, precision=HI))
-    o = jnp.concatenate(outs, 0).reshape(S, H * hd)
-    h = h + _mm(o, a["wo"].reshape(H * hd, d), quant)
-    x = _rms(h, lw["ln2"]["scale"], eps)
-    m = lw["mlp"]
-    f = s["d_ff"]
-    c = -(-f // FF_CHUNKS)
-    y = jnp.zeros_like(h)
-    for f0 in range(0, f, c):
-        g = _mm(x, m["wg"][:, f0:f0 + c], quant)
-        u = _mm(x, m["wu"][:, f0:f0 + c], quant)
-        y = y + _mm(jax.nn.silu(g) * u, m["wd"][f0:f0 + c], quant)
-    return h + y
+    return jnp.concatenate(outs, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("spec_items", "quant"))
 def _hidden(spec_items, quant, params, tokens):
     s = dict(spec_items)
-    h = params["embed"][tokens].astype(jnp.float32)
-
-    def body(hh, lw):
-        return _layer(s, quant, hh, lw), None
-    h, _ = jax.lax.scan(body, h, params["layers"])
-    return _rms(h, params["final_norm"]["scale"], s["norm_eps"])
+    return arch(s).hidden(s, quant, params, tokens)
 
 
 @functools.partial(jax.jit, static_argnames=("spec_items", "quant"))
 def _logits(spec_items, quant, params, rows):
     s = dict(spec_items)
-    table = params["embed"] if s["tie"] else params["lm_head"]   # (V, d)
-    return _mm(rows, table.T, quant)
+    return arch(s).logits(s, quant, params, rows)
 
 
 def _padded(tokens) -> np.ndarray:

@@ -12,30 +12,20 @@ from __future__ import annotations
 import importlib.util
 import sys
 
-from .models import CHIP_DIR
-
-# ModelConfig fields a spec determines, with the spec key they come from
-_FIELDS = {"num_layers": "layers", "d_model": "d_model",
-           "num_heads": "heads", "num_kv_heads": "kv_heads",
-           "d_ff": "d_ff", "vocab_size": "vocab", "qkv_bias": "qkv_bias",
-           "qk_norm": "qk_norm", "rope_theta": "rope_theta",
-           "tie_embeddings": "tie"}
-_FIXED = {"family": "dense", "mlp_type": "swiglu", "norm_type": "rmsnorm",
-          "rmsnorm_offset": False, "embed_scale": False,
-          "logit_softcap": 0.0, "pos_embedding": "rope",
-          "dtype": "bfloat16", "moe_num_experts": 0}
+from .models import CHIP_DIR, arch
 
 
 def model_config(spec: dict):
+    """The program's ``ModelConfig`` of a spec, with the fields its
+    architecture module gives."""
     from repro.configs.base import ModelConfig
-    kw = {f: spec[k] for f, k in _FIELDS.items()}
-    kw.update(_FIXED)
-    return ModelConfig(name=spec["name"], head_dim=spec["head_dim"], **kw)
+    return ModelConfig(name=spec["name"], **arch(spec).program_fields(spec))
 
 
-def _shape_of(cfg) -> dict:
-    out = {f: getattr(cfg, f) for f in list(_FIELDS) + list(_FIXED)}
-    out["head_dim"] = cfg.resolved_head_dim
+def _shape_of(cfg, fields) -> dict:
+    out = {f: getattr(cfg, f) for f in fields}
+    if "head_dim" in out:
+        out["head_dim"] = cfg.resolved_head_dim
     return out
 
 
@@ -47,15 +37,19 @@ def register(conf: dict) -> str:
     """Make the pair of ``conf`` known to the program; returns the arch name
     to pass to ``build_real_engine``."""
     from repro import configs
-    t, d = model_config(conf["target"]), model_config(conf["draft"])
+    specs = (conf["target"], conf["draft"])
+    t, d = (model_config(s) for s in specs)
     name = conf["registered_as"]
     if name:
         have = (configs.get_config(name), configs.get_draft_config(name))
-        for want, got in zip((t, d), have):
-            if _shape_of(want) != _shape_of(got):
+        for s, want, got in zip(specs, (t, d), have):
+            # every field the spec's module sets
+            fields = arch(s).program_fields(s)
+            if _shape_of(want, fields) != _shape_of(got, fields):
                 raise ConfigMismatch(
-                    f"the program registers {name!r} as {_shape_of(got)}, "
-                    f"the configuration file says {_shape_of(want)}")
+                    f"the program registers {name!r} as "
+                    f"{_shape_of(got, fields)}, the configuration file says "
+                    f"{_shape_of(want, fields)}")
         return name
     configs._PAIRS[conf["name"]] = (t, d)
     return conf["name"]
