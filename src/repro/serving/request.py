@@ -44,9 +44,14 @@ class Request:
                                       # reaped, not finished
 
 
-@dataclass
+@dataclass(eq=False)
 class Sequence:
-    """A request admitted to the running batch."""
+    """A request admitted to the running batch.
+
+    One admission's lifecycle object: a preempted request comes back as a
+    new ``Sequence``, so sequences compare and hash by identity.  Every
+    membership test and ``remove`` on ``scheduler.running`` is then a
+    pointer comparison, not a field-by-field one of two dataclasses."""
 
     request: Request
     slot: int = -1

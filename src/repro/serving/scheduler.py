@@ -228,12 +228,13 @@ class ContinuousBatchingScheduler:
             budget -= n
 
         # chunks whose sequence was preempted later in this same pass are
-        # void — drop them by object identity (the same request may have
-        # been re-admitted above as a fresh Sequence under the same key)
-        alive = {id(s) for s in self.running}
+        # void — drop them (sequences hash by identity, so a request
+        # re-admitted above as a fresh Sequence under the same key is kept
+        # apart from its preempted one)
+        alive = set(self.running)
         batch.prefill_chunks = [(s, n) for s, n in batch.prefill_chunks
-                                if id(s) in alive]
-        batch.admitted = [s for s in batch.admitted if id(s) in alive]
+                                if s in alive]
+        batch.admitted = [s for s in batch.admitted if s in alive]
         batch.decode = [s for s in self.running
                         if s.prompt_remaining == 0 and not s.done]
         return batch

@@ -176,3 +176,77 @@ def test_preemption_recompute():
     assert s.num_waiting == 1     # b requeued for recompute
     assert a in s.running
     bm.check_invariants()
+
+
+# ---------------------------------------------------------------------------
+# running sequences compare by identity
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("leave", ["finish", "preempt"])
+def test_equal_sequences_are_distinct_running_members(leave):
+    """Two sequences with equal field values (a request's stale and its
+    re-admitted lifecycle object, say) are distinct members of ``running``;
+    ``finish`` and ``preempt`` remove exactly the object passed."""
+    bm = BlockManager(16, 4)
+    s = ContinuousBatchingScheduler(bm, max_batch=8, watermark_frac=0.0)
+    a = Sequence(Request(0, 0.0, 8, 8))
+    b = Sequence(Request(0, 0.0, 8, 8))
+    assert a is not b and a != b and len({a, b}) == 2
+    s.running.extend([a, b])
+    assert a in s.running and b in s.running
+    getattr(s, leave)(b)
+    assert len(s.running) == 1 and s.running[0] is a
+    getattr(s, leave)(a)
+    assert s.running == []
+
+
+class _ReleaseLog:
+    """Backend stub for ``_commit_decode``: records what it releases."""
+
+    def __init__(self):
+        self.released = []
+
+    def release(self, seq):
+        self.released.append(seq)
+
+
+def test_commit_decode_compares_no_values(monkeypatch):
+    """A 64-row gamma-0 commit finds its rows in ``running`` by identity:
+    no ``Request`` is compared by value.  In the same commit the oldest
+    row's growth preempts the youngest row, which is then skipped, and
+    rows at their output limit finish and are released."""
+    from repro.serving.engine import ServingEngine
+
+    bm = BlockManager(128, 4)
+    s = ContinuousBatchingScheduler(bm, max_batch=64, watermark_frac=0.0)
+    at_limit = range(10, 20)
+    for i in range(64):
+        # row 0 fills its two blocks, every other row leaves one slot free:
+        # the 128-block pool is full and only row 0's commit needs a block
+        s.add_request(Request(i, i * 0.01, 7 if i == 0 else 6,
+                              1 if i in at_limit else 100))
+    seqs = s.schedule()
+    assert len(seqs) == 64 and bm.num_free == 0
+    backend = _ReleaseLog()
+    eng = ServingEngine(backend, s, policy=None)
+
+    def no_value_compare(self, other):
+        raise AssertionError("Request compared by value")
+
+    with monkeypatch.context() as mp:
+        mp.setattr(Request, "__eq__", no_value_compare)
+        finished, clipped = eng._commit_decode(seqs, [1] * 64, 0)
+
+    youngest = seqs[63]
+    assert (finished, clipped) == (len(at_limit), 0)
+    assert youngest not in s.running and youngest.generated == 0
+    assert s.waiting[0] is youngest.request
+    assert seqs[0].generated == 1 and len(bm.tables[0]) == 3
+    assert backend.released == [seqs[i] for i in at_limit]
+    assert [r.req_id for r in eng.metrics.requests] == list(at_limit)
+    assert all(seqs[i] not in s.running and i not in bm.tables
+               for i in at_limit)
+    assert len(s.running) == 64 - 1 - len(at_limit)
+    assert all(q.generated == 1 for q in s.running)
+    bm.check_invariants()
